@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core import baselines, pq as pqmod
 from repro.core.config import ProberConfig
+from repro.utils import compile_cache
 
 
 def _time(fn, *args, reps=20):
@@ -59,4 +60,5 @@ def run(dims=(128, 304, 960, 1776), n: int = 20000):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     run()

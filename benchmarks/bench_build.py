@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from benchmarks import common
 from repro.core import lsh, neighbors, pq as pqmod
 from repro.core.config import ProberConfig
+from repro.utils import compile_cache
 
 
 def run(datasets=None):
@@ -50,4 +51,5 @@ def run(datasets=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     run()

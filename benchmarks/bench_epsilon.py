@@ -3,6 +3,7 @@ turning point after which smaller eps stops helping."""
 from __future__ import annotations
 
 from benchmarks import common
+from repro.utils import compile_cache
 
 
 def run(dataset: str = "sift", eps_grid=(0.1, 0.03, 0.01, 0.003, 0.001)):
@@ -19,4 +20,5 @@ def run(dataset: str = "sift", eps_grid=(0.1, 0.03, 0.01, 0.003, 0.001)):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     run()

@@ -44,6 +44,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import estimator as E
+from repro.utils import compile_cache
 
 BATCH_SIZES = (1, 8, 64, 256)
 SKEW_HEAVY_FRAC = 0.125     # fraction of large-tau requests in the skew mix
@@ -293,6 +294,7 @@ def run_workload_sweep(dataset: str = "sift", scenarios=None,
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     # distinct tags per sweep — the batch/skew rows are the longitudinal
     # scheduling record and must not be clobbered by a methods-only run;
     # workload rows share the latency tag but merge (carry a "workload"

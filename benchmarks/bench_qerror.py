@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 
 from benchmarks import common
+from repro.utils import compile_cache
 
 
 def run(datasets=None):
@@ -30,4 +31,5 @@ def run(datasets=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     run()

@@ -29,9 +29,9 @@ import jax.numpy as jnp                                 # noqa: E402
 import numpy as np                                      # noqa: E402
 
 from benchmarks import common                           # noqa: E402
-from repro import compat                                # noqa: E402
 from repro.core import distributed as D, estimator as E, updates  # noqa: E402
 from repro.data import vectors as V                     # noqa: E402
+from repro.utils import compile_cache                   # noqa: E402
 
 
 def _stream_single(x, cfg, key, n0, chunk):
@@ -111,8 +111,9 @@ def run(n: int = 65536, dim: int = 32, chunk: int = 4096,
             assert int(jax.device_get(st.index.n_valid)) == n
             q_local = q_sync = _qerr_single(st, cfg, qs, taus, cards, key)
         else:
-            mesh = compat.make_mesh((s,), ("data",),
-                                    devices=jax.devices()[:s])
+            mesh = jax.make_mesh((s,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,),
+                                 devices=jax.devices()[:s])
             st, t_stream, t_warm = _stream_sharded(x, cfg, key, n0, chunk,
                                                    mesh)
             nv = np.asarray(jax.device_get(st.index.n_valid))
@@ -147,6 +148,7 @@ def run(n: int = 65536, dim: int = 32, chunk: int = 4096,
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     if "--quick" in sys.argv:
         run(shard_counts=(1, 8))
     else:

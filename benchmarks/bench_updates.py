@@ -26,6 +26,7 @@ import jax
 from benchmarks import common
 from repro.core import baselines, estimator as E, updates
 from repro.data import vectors as V
+from repro.utils import compile_cache
 
 
 def _timed(fn):
@@ -179,6 +180,7 @@ def run(datasets=("sift", "glove"), chunk: int = 1024):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     if "--stream" in sys.argv:
         stream_run()
     else:

@@ -17,12 +17,15 @@ from __future__ import annotations
 
 import sys
 
+from repro.utils import compile_cache
+
 # section name -> the BENCH_<tag>.json snapshot it is documented to write
 SNAPSHOT_TAGS = {"latency": "methods", "batch": "latency",
                  "workload": "latency", "qerror": "qerror"}
 
 
 def main() -> None:
+    compile_cache.enable()
     which = set(sys.argv[1:]) or {"qerror", "latency", "batch", "workload",
                                   "build", "adc", "epsilon", "updates",
                                   "roofline"}

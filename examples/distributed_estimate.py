@@ -1,23 +1,22 @@
-"""Distributed Dynamic Prober over an 8-device mesh (shard_map + psum):
-the dataset is partitioned, every shard probes locally, cardinality is the
-psum of local estimates (DESIGN.md §4).
+"""Distributed Dynamic Prober over a mesh of every visible device
+(shard_map + psum): the dataset is partitioned, every shard probes locally,
+cardinality is the psum of local estimates (DESIGN.md §4).
 
   PYTHONPATH=src python examples/distributed_estimate.py
-  (sets its own XLA_FLAGS; run as a standalone script)
+  # several devices on a CPU host:
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python examples/distributed_estimate.py
 """
-import os
+import jax
+import jax.numpy as jnp
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+from repro.core import distributed as D, estimator as E
+from repro.core.config import ProberConfig
 
-import jax                                             # noqa: E402
-import jax.numpy as jnp                                # noqa: E402
-
-from repro import compat                               # noqa: E402
-from repro.core import distributed as D, estimator as E  # noqa: E402
-from repro.core.config import ProberConfig             # noqa: E402
-
-print("devices:", len(jax.devices()))
-mesh = compat.make_mesh((8,), ("data",))
+n_dev = len(jax.devices())
+print("devices:", n_dev)
+mesh = jax.make_mesh((n_dev,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (16000, 64))
@@ -25,7 +24,8 @@ cfg = ProberConfig(n_tables=2, n_funcs=8, ring_budget=1024,
                    central_budget=1024, chunk=128)
 
 state, params = D.build_sharded(x, cfg, key, mesh)
-print("sharded index built: 8 local partitions of", x.shape[0] // 8)
+print(f"sharded index built: {n_dev} local partitions of",
+      x.shape[0] // n_dev)
 
 qs = x[:4] + 0.01
 d2 = jnp.sort(jnp.sum((x - qs[0][None]) ** 2, axis=-1))

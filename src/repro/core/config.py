@@ -78,8 +78,8 @@ class ProberConfig:
                                # new shape (power of two recommended)
     # --- kernels ---
     use_kernels: bool = False  # route hot loops through the Pallas kernels
-                               # (native on TPU; interpret=True elsewhere —
-                               # correct but slow, so off by default on CPU)
+                               # (compiled on TPU; interpreted on the CPU —
+                               # correct but slow, so off by default)
 
     @property
     def a_const(self) -> float:

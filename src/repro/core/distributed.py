@@ -3,9 +3,7 @@
 Cardinality is additive over a dataset partition, so the estimator is
 embarrassingly parallel: shard the points over the ("pod","data") mesh axes,
 replicate the LSH/PQ *functions* (so codes are globally consistent), run the
-full adaptive prober per shard, and ``psum`` the local estimates. All mesh /
-shard_map construction goes through :mod:`repro.compat` so the same code
-runs on the pinned jax 0.4.37 and on current jax.
+full adaptive prober per shard, and ``psum`` the local estimates.
 
 Two stopping modes (``estimate_sharded(mode=...)``):
   * ``local`` (default) — each shard applies the ε-stopping to its own
@@ -45,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import estimator as est_mod
 from repro.core import lsh, updates
 from repro.core.config import ProberConfig
@@ -102,8 +99,8 @@ def build_sharded(x_global: jax.Array, cfg: ProberConfig, key: jax.Array,
     spec = P(data_axes)
     xs = jax.device_put(x_global, NamedSharding(mesh, spec))
 
-    @compat.shard_map(mesh=mesh, in_specs=(spec, P()), out_specs=spec,
-                      check_vma=False)
+    @jax.shard_map(mesh=mesh, in_specs=(spec, P()), out_specs=spec,
+                   check_vma=False)
     def _build(x_local, k):
         k = _fold_axis_index(k, data_axes)
         st = est_mod.build(x_local, cfg, k, params=params,
@@ -132,9 +129,9 @@ def _sharded_ingest_step(mesh: Mesh, data_axes, cfg: ProberConfig):
                                    axis_name=data_axes)
         return jax.tree_util.tree_map(lambda a: a[None], out)
 
-    return jax.jit(compat.shard_map(step, mesh=mesh,
-                                    in_specs=(spec, spec, spec),
-                                    out_specs=spec, check_vma=False))
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 @lru_cache(maxsize=None)
@@ -148,8 +145,8 @@ def _sharded_grow_step(mesh: Mesh, data_axes, new_capacity: int):
         st = est_mod._grow(st, new_capacity)
         return jax.tree_util.tree_map(lambda a: a[None], st)
 
-    return jax.jit(compat.shard_map(step, mesh=mesh, in_specs=(spec,),
-                                    out_specs=spec, check_vma=False))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
 
 
 def route_round_robin(x_new: np.ndarray, shards: int, offset: int):
@@ -223,9 +220,9 @@ def _sharded_estimate_step(mesh: Mesh, data_axes, cfg: ProberConfig,
         local = est_mod.estimate_batch(st, q_all, t_all, cfg, k)
         return jax.lax.psum(local, data_axes)
 
-    return jax.jit(compat.shard_map(_est, mesh=mesh,
-                                    in_specs=(spec, P(), P(), P()),
-                                    out_specs=P(), check_vma=False))
+    return jax.jit(jax.shard_map(_est, mesh=mesh,
+                                 in_specs=(spec, P(), P(), P()),
+                                 out_specs=P(), check_vma=False))
 
 
 def estimate_sharded(state, qs: jax.Array, taus: jax.Array, cfg: ProberConfig,

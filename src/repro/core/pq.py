@@ -12,6 +12,7 @@ K-means runs fully vectorised across subspaces; centroid updates use
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -55,43 +56,106 @@ def split_subspaces(x: jax.Array, m: int) -> jax.Array:
     return x.reshape(n, m, d // m)
 
 
-def assign(centroids: jax.Array, xs: jax.Array) -> jax.Array:
-    """Nearest-centroid assignment per subspace. xs: (N, M, ds) -> (N, M)."""
+ASSIGN_ROWS = 65536   # rows per assignment block: bounds the (rows, M, Kc)
+                      # distance tile (128 MiB at M=32, Kc=256) — a single
+                      # (N, M, Kc) tile at N=1M is 8 GiB and does not fit
+                      # one 16 GB chip next to its own temporaries
+
+
+def _assign_block(centroids: jax.Array, xs: jax.Array) -> jax.Array:
     # dist^2 = |x|^2 - 2 x.c + |c|^2 ; argmin over Kc
-    x2 = jnp.sum(xs ** 2, axis=-1, keepdims=True)            # (N, M, 1)
+    x2 = jnp.sum(xs ** 2, axis=-1, keepdims=True)            # (n, M, 1)
     c2 = jnp.sum(centroids ** 2, axis=-1)                    # (M, Kc)
-    xc = jnp.einsum("nms,mks->nmk", xs, centroids)           # (N, M, Kc)
+    xc = jnp.einsum("nms,mks->nmk", xs, centroids)           # (n, M, Kc)
     d2 = x2 - 2.0 * xc + c2[None]
     return jnp.argmin(d2, axis=-1).astype(jnp.int32)
 
 
+def _row_blocks(a: jax.Array, rows: int, fill=0) -> jax.Array:
+    """(N, ...) -> (ceil(N/rows), rows, ...), the tail padded with ``fill``."""
+    pad = (-a.shape[0]) % rows
+    a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                constant_values=fill)
+    return a.reshape(-1, rows, *a.shape[1:])
+
+
+def _block_rows(n: int) -> int:
+    return max(min(n, ASSIGN_ROWS), 1)
+
+
+def _subspace_blocks(xs: jax.Array, rows: int) -> jax.Array:
+    """(N, M, ds) -> (blocks, rows, M·ds) row blocks of the flat vectors.
+
+    The blocks are cut from the (N, d) view and split per block: the TPU
+    pads the ds-wide minor dimension of a whole (N, M, ds) array to 128
+    lanes (16 GiB at N=1M, d=128), which a block-sized view never is."""
+    return _row_blocks(xs.reshape(xs.shape[0], -1), rows)
+
+
+def assign(centroids: jax.Array, xs: jax.Array) -> jax.Array:
+    """Nearest-centroid assignment per subspace. xs: (N, M, ds) -> (N, M).
+
+    Rows are assigned in blocks of ``ASSIGN_ROWS`` (one block when N is
+    smaller), so device memory stays bounded at any corpus size."""
+    n, m, ds = xs.shape
+    rows = _block_rows(n)
+    codes = jax.lax.map(
+        lambda b: _assign_block(centroids, b.reshape(rows, m, ds)),
+        _subspace_blocks(xs, rows))
+    return codes.reshape(-1, m)[:n]
+
+
+def _centroid_sums(xs: jax.Array, codes: jax.Array, kc: int):
+    """Per-(subspace, centroid) coordinate sums (M, Kc, ds) and member
+    counts (M, Kc) of the points ``xs`` (N, M, ds) under ``codes`` (N, M),
+    accumulated over the row blocks of :func:`assign` (a whole (N·M, ds)
+    scatter source is lane-padded like the view :func:`_subspace_blocks`
+    avoids)."""
+    n, m, ds = xs.shape
+    rows = _block_rows(n)
+    offs = (jnp.arange(m, dtype=jnp.int32) * kc)[None, :]
+
+    def one(acc, blk):
+        xb, cb = blk
+        seg = (cb + offs).reshape(-1)       # padding rows: >= m*kc, dropped
+        sums = jax.ops.segment_sum(xb.reshape(rows * m, ds), seg,
+                                   num_segments=m * kc)
+        cnts = jax.ops.segment_sum(jnp.ones(seg.shape, jnp.float32), seg,
+                                   num_segments=m * kc)
+        return (acc[0] + sums, acc[1] + cnts), None
+
+    init = (jnp.zeros((m * kc, ds), xs.dtype), jnp.zeros((m * kc,),
+                                                         jnp.float32))
+    (sums, cnts), _ = jax.lax.scan(
+        one, init, (_subspace_blocks(xs, rows),
+                    _row_blocks(codes, rows, fill=m * kc)))
+    return sums.reshape(m, kc, ds), cnts.reshape(m, kc)
+
+
+@partial(jax.jit, static_argnames=("cfg",))
 def fit(x: jax.Array, cfg: ProberConfig, key: jax.Array) -> PQIndex:
-    """Lloyd's k-means per subspace, vectorised across all M subspaces."""
+    """Lloyd's k-means per subspace, vectorised across all M subspaces.
+
+    Jitted as one program: run op by op, the (N, M, ds) subspace view
+    would be materialised with its ds-wide minor dimension padded to 128
+    lanes on a TPU — 16 GiB at N=1M, d=128."""
     m, kc = cfg.pq_m, cfg.pq_kc
     assert kc <= 256, f"Kc={kc} must fit a uint8 code"
     xs = split_subspaces(x, m)                               # (N, M, ds)
-    n, _, ds = xs.shape
+    n = xs.shape[0]
     init_rows = jax.random.choice(key, n, (kc,), replace=n < kc)
     centroids = jnp.swapaxes(xs[init_rows], 0, 1)            # (M, Kc, ds)
 
     def step(centroids, _):
         codes = assign(centroids, xs)                        # (N, M)
-        seg = (codes + (jnp.arange(m, dtype=jnp.int32) * kc)[None, :]).reshape(-1)
-        flat = xs.reshape(n * m, ds)
-        sums = jax.ops.segment_sum(flat, seg, num_segments=m * kc)
-        cnts = jax.ops.segment_sum(jnp.ones((n * m,), jnp.float32), seg,
-                                   num_segments=m * kc)
-        sums = sums.reshape(m, kc, ds)
-        cnts = cnts.reshape(m, kc)
+        sums, cnts = _centroid_sums(xs, codes, kc)
         new = jnp.where(cnts[..., None] > 0, sums / jnp.maximum(cnts[..., None], 1.0),
                         centroids)
         return new, None
 
     centroids, _ = jax.lax.scan(step, centroids, None, length=cfg.pq_iters)
     codes = assign(centroids, xs)
-    seg = (codes + (jnp.arange(m, dtype=jnp.int32) * kc)[None, :]).reshape(-1)
-    counts = jax.ops.segment_sum(jnp.ones((n * m,), jnp.float32), seg,
-                                 num_segments=m * kc).reshape(m, kc)
+    _, counts = _centroid_sums(xs, codes, kc)
     resid = reconstruction_residual(centroids, codes, xs)
     codes8 = codes.astype(jnp.uint8)
     packed = None
@@ -139,10 +203,20 @@ def unpack_codes(packed: jax.Array) -> jax.Array:
 
 def reconstruction_residual(centroids: jax.Array, codes: jax.Array,
                             xs: jax.Array) -> jax.Array:
-    """||x - q(x)|| per point; xs is (N, M, ds)."""
-    m = centroids.shape[0]
-    recon = centroids[jnp.arange(m)[None, :], codes]     # (N, M, ds)
-    return jnp.sqrt(jnp.sum((xs - recon) ** 2, axis=(-1, -2)))
+    """||x - q(x)|| per point; xs is (N, M, ds). Row-blocked like
+    :func:`assign`."""
+    n, m, ds = xs.shape
+    rows = _block_rows(n)
+
+    def one(blk):
+        cb, xb = blk
+        recon = centroids[jnp.arange(m)[None, :], cb]     # (rows, M, ds)
+        diff = xb.reshape(rows, m, ds) - recon
+        return jnp.sqrt(jnp.sum(diff ** 2, axis=(-1, -2)))
+
+    resid = jax.lax.map(one, (_row_blocks(codes, rows),
+                              _subspace_blocks(xs, rows)))
+    return resid.reshape(-1)[:n]
 
 
 def adc_table(pq: PQIndex, q: jax.Array) -> jax.Array:

@@ -16,6 +16,7 @@ threshold achieving that cardinality (computed from exact sorted distances).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict
 
 import jax
@@ -56,6 +57,31 @@ def make_corpus(key: jax.Array, n: int, dim: int, *, n_clusters: int = 32,
     return x.astype(jnp.float32)
 
 
+@jax.jit
+def _taus_and_cards(x: jax.Array, queries: jax.Array, targets: jax.Array):
+    """Per query: the tau grid reaching each target cardinality, and the
+    exact count at each tau. ``x`` is an argument, not a closed-over
+    constant, so the corpus never becomes part of the compiled program."""
+    n = x.shape[0]
+
+    def taus_for(q):
+        d2 = jnp.sum((x - q[None, :]) ** 2, axis=-1)
+        d2s = jnp.sort(d2)
+        # minimal tau reaching each target cardinality; midpoint to the next
+        # distinct distance so ties don't flip the exact count
+        at = jnp.sqrt(d2s[targets - 1])
+        nxt = jnp.sqrt(d2s[jnp.minimum(targets, n - 1)])
+        return jnp.where(targets < n, 0.5 * (at + nxt), at + 1e-3)
+
+    def card_for(qt):
+        q, ts = qt
+        d2 = jnp.sum((x - q[None, :]) ** 2, axis=-1)
+        return jnp.sum(d2[None, :] <= (ts ** 2)[:, None], axis=-1)
+
+    taus = jax.lax.map(taus_for, queries)
+    return taus, jax.lax.map(card_for, (queries, taus))
+
+
 def paper_query_workload(key: jax.Array, x: jax.Array, n_queries: int,
                          n_taus: int = 12, max_card: int | None = None):
     """Paper §6.1: geometric target-cardinality grid, tau = minimal threshold.
@@ -68,34 +94,21 @@ def paper_query_workload(key: jax.Array, x: jax.Array, n_queries: int,
     qidx = jax.random.choice(key, n, (n_queries,), replace=False)
     queries = x[qidx]
     targets = np.unique(np.geomspace(1, max_card, n_taus).astype(np.int64))
-    targets_j = jnp.asarray(targets)
-
-    @jax.jit
-    def taus_for(q):
-        d2 = jnp.sum((x - q[None, :]) ** 2, axis=-1)
-        d2s = jnp.sort(d2)
-        # minimal tau reaching each target cardinality; midpoint to the next
-        # distinct distance so ties don't flip the exact count
-        at = jnp.sqrt(d2s[targets_j - 1])
-        nxt = jnp.sqrt(d2s[jnp.minimum(targets_j, n - 1)])
-        return jnp.where(targets_j < n, 0.5 * (at + nxt), at + 1e-3)
-
-    taus = jax.lax.map(taus_for, queries)
-
-    @jax.jit
-    def card_for(q, ts):
-        d2 = jnp.sum((x - q[None, :]) ** 2, axis=-1)
-        return jnp.sum(d2[None, :] <= (ts ** 2)[:, None], axis=-1)
-
-    cards = jax.lax.map(lambda qt: card_for(qt[0], qt[1]), (queries, taus))
+    taus, cards = _taus_and_cards(x, queries, jnp.asarray(targets))
     return queries, taus, cards
+
+
+def default_seed(name: str) -> int:
+    """Seed of a corpus loaded without a key: a stable function of its
+    name (``hash`` of a str differs per process under PYTHONHASHSEED)."""
+    return zlib.crc32(name.encode()) % (2 ** 31)
 
 
 def load(name: str, key: jax.Array | None = None, n_queries: int = 32,
          scale: float = 1.0) -> VectorDataset:
     """Build a named surrogate corpus + paper-protocol query workload."""
     if key is None:
-        key = jax.random.PRNGKey(hash(name) % (2 ** 31))
+        key = jax.random.PRNGKey(default_seed(name))
     n, dim = CORPORA[name]
     n = int(n * scale)
     kx, kq = jax.random.split(key)

@@ -14,7 +14,6 @@ import dataclasses
 
 import jax
 
-from repro import compat
 from repro.launch.mesh import make_production_mesh  # noqa: F401  (re-export)
 
 
@@ -25,7 +24,8 @@ class MeshPlan:
     model: int
 
     def make(self):
-        return compat.make_mesh((self.data, self.model), ("data", "model"))
+        return jax.make_mesh((self.data, self.model), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def plan_remesh(n_alive: int, model_parallel: int) -> MeshPlan:

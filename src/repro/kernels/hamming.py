@@ -22,7 +22,7 @@ def _kernel(codes_ref, qcode_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def hamming(bucket_codes: jax.Array, qcode: jax.Array, *, bb: int = 1024,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """bucket_codes (B, K) int32, qcode (K,) → (B,) int32 distances."""
     b, k = bucket_codes.shape
     bb = min(bb, b)

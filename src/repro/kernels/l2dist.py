@@ -27,7 +27,7 @@ def _kernel(x_ref, q_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "bq", "interpret"))
 def l2dist(x: jax.Array, q: jax.Array, *, bn: int = 256, bq: int = 128,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool = False) -> jax.Array:
     """x (N, d), q (Q, d) → squared distances (N, Q) float32."""
     n, d = x.shape
     nq = q.shape[0]

@@ -29,7 +29,7 @@ def _kernel(x_ref, a_ref, b_ref, w_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "bf", "interpret"))
 def lsh_hash(x: jax.Array, a: jax.Array, b: jax.Array, w: jax.Array,
-             *, bn: int = 256, bf: int = 128, interpret: bool = True
+             *, bn: int = 256, bf: int = 128, interpret: bool = False
              ) -> jax.Array:
     """x (N, d), a (d, F), b (F,), w (F,) → codes (N, F) int32."""
     n, d = x.shape

@@ -24,6 +24,7 @@ from repro.core.config import ProberConfig
 from repro.models import get_family
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.semantic import SemanticPlanner
+from repro.utils import compile_cache
 
 
 def main(argv=None):
@@ -46,6 +47,7 @@ def main(argv=None):
                     help="distributed stopping mode (DESIGN.md §4); only "
                          "meaningful with --shards > 1")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(args.seed)
     cfg = (configs.get_smoke_config(args.arch) if args.scale == "smoke"
@@ -61,15 +63,15 @@ def main(argv=None):
                         central_budget=1024, chunk=128)
     mesh = None
     if args.shards > 1:
-        from repro import compat
         assert args.corpus % args.shards == 0, \
             f"--shards {args.shards} must divide --corpus {args.corpus}"
         assert len(jax.devices()) >= args.shards, \
             f"--shards {args.shards} needs that many jax devices " \
             f"(have {len(jax.devices())}; set XLA_FLAGS=" \
             f"--xla_force_host_platform_device_count={args.shards})"
-        mesh = compat.make_mesh((args.shards,), ("data",),
-                                devices=jax.devices()[:args.shards])
+        mesh = jax.make_mesh((args.shards,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,),
+                             devices=jax.devices()[:args.shards])
     planner = SemanticPlanner(corpus, pcfg, key, max_calls=args.max_calls,
                               slot_budget=args.slots, mesh=mesh,
                               mode=args.stopping)

@@ -13,7 +13,7 @@ def compile_events():
     cached executions add nothing. Shared by the recompile-free contract
     tests (test_updates.py, test_compact.py; test_sharding.py carries its
     own copy inside its subprocess scripts)."""
-    from jax._src import monitoring
+    from jax import monitoring
     events: list = []
 
     def cb(event, **kw):
@@ -24,4 +24,4 @@ def compile_events():
     try:
         yield events
     finally:
-        monitoring._unregister_event_listener_by_callback(cb)
+        monitoring.unregister_event_listener(cb)

@@ -1,10 +1,5 @@
 """Sharding-rule unit tests + 8-device CPU integration tests (subprocess so
-the forced device count doesn't leak into other tests).
-
-All mesh construction goes through ``repro.compat`` so the tests run on the
-pinned jax 0.4.37 (no ``jax.sharding.AxisType``, ``shard_map`` still in
-``jax.experimental``) as well as on current jax.
-"""
+the forced device count doesn't leak into other tests)."""
 import os
 import subprocess
 import sys
@@ -15,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import configs, compat
+from repro import configs
 from repro.launch import specs as S
 
 
@@ -46,7 +41,8 @@ def test_sharded_paths_on_trivial_mesh():
     path bit-for-bit (one shard pools only with itself)."""
     from repro.core import distributed as D, estimator as E
     from repro.core.config import ProberConfig
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (2000, 16))
     cfg = ProberConfig(n_tables=2, n_funcs=6, ring_budget=512,
@@ -76,10 +72,11 @@ def test_param_specs_divisibility_fallback():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from jax.sharding import PartitionSpec as P
-        from repro import configs, compat
+        from repro import configs
         from repro.launch import specs as S
         from repro.sharding import rules
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = configs.get_config("whisper-medium")
         params = S.param_specs_for(cfg)
         specs = rules.param_specs(params, mesh, "fsdp_tp")
@@ -152,10 +149,10 @@ def test_8dev_distributed_estimator():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro import compat
         from repro.core.config import ProberConfig
         from repro.core import estimator as E, distributed as D
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (4000, 32))
         cfg = ProberConfig(n_tables=1, n_funcs=6, ring_budget=1024,
@@ -188,11 +185,11 @@ def test_8dev_sharded_ingest_recompile_free():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from jax._src import monitoring
-        from repro import compat
+        from jax import monitoring
         from repro.core.config import ProberConfig
         from repro.core import estimator as E, distributed as D
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (4000, 32))
         cfg = ProberConfig(n_tables=1, n_funcs=6, ring_budget=1024,
@@ -216,7 +213,7 @@ def test_8dev_sharded_ingest_recompile_free():
         state, nv = D.update_sharded(state, np.asarray(x[2800:3200]), cfg,
                                      mesh, n_valid=nv)
         est = D.estimate_sharded(state, qs, taus, cfg, key, mesh)
-        monitoring._unregister_event_listener_by_callback(cb)
+        monitoring.unregister_event_listener(cb)
         assert events == [], f"sharded in-capacity ingest recompiled: "\\
             f"{events}"
         assert nv.tolist() == [400] * 8, nv          # round-robin balance
@@ -244,10 +241,10 @@ def test_8dev_sync_beats_local_on_skewed_shards():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.core.config import ProberConfig
         from repro.core import estimator as E, distributed as D
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         d, S, n_shard, tau, n_sp = 16, 8, 1000, 3.0, 10
         def shell(n, r_lo, r_hi):

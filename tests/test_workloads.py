@@ -18,6 +18,17 @@ def ds():
     return vectors.load("sift", n_queries=4, scale=0.02)   # 800 x 128
 
 
+def test_default_corpus_seed_is_stable(ds):
+    """A keyless load draws the same corpus in every process: its seed is
+    a fixed function of the name, not of PYTHONHASHSEED."""
+    import jax
+    from repro.data import vectors
+    assert vectors.default_seed("sift") == 2064079301
+    again = vectors.load("sift", key=jax.random.PRNGKey(2064079301),
+                         n_queries=4, scale=0.02)
+    np.testing.assert_array_equal(np.asarray(ds.x), np.asarray(again.x))
+
+
 def test_streams_deterministic(ds):
     a = workloads.generate(ds, "zipf", n_events=64, pool=16, seed=7)
     b = workloads.generate(ds, "zipf", n_events=64, pool=16, seed=7)
