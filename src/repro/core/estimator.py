@@ -133,14 +133,20 @@ def estimate(state: ProberState, q: jax.Array, tau: jax.Array,
     return prober.estimate(state.index, state.x, q, tau, cfg, key)
 
 
+@jax.named_scope("probe/prep")
+def _query_luts(pq: pqmod.PQIndex, qs: jax.Array, cfg: ProberConfig):
+    """Per-query PQ LUTs of a batch: the (Q, M, Kc) float stack, or a
+    batched QuantLUT (DESIGN.md §11)."""
+    return jax.vmap(lambda q: pqmod.build_query_lut(pq, q, cfg))(qs)
+
+
 @partial(jax.jit, static_argnames=("cfg",))
 def estimate_batch(state: ProberState, qs: jax.Array, taus: jax.Array,
                    cfg: ProberConfig, key: jax.Array) -> jax.Array:
     """Estimate Q cardinalities in one jitted step (see module docstring)."""
     keys = jax.random.split(key, qs.shape[0])
     if cfg.use_pq and state.pq is not None:
-        # (Q, M, Kc) float LUT stack, or batched QuantLUT (DESIGN.md §11)
-        luts = jax.vmap(lambda q: pqmod.build_query_lut(state.pq, q, cfg))(qs)
+        luts = _query_luts(state.pq, qs, cfg)
         return prober.estimate_batch(state.index, state.x, qs, taus, cfg, keys,
                                      pq_codes=state.pq.codes, pq_luts=luts,
                                      pq_resid=state.pq.resid,
@@ -159,7 +165,7 @@ def estimate_batch_stats(state: ProberState, qs: jax.Array, taus: jax.Array,
     key."""
     keys = jax.random.split(key, qs.shape[0])
     if cfg.use_pq and state.pq is not None:
-        luts = jax.vmap(lambda q: pqmod.build_query_lut(state.pq, q, cfg))(qs)
+        luts = _query_luts(state.pq, qs, cfg)
         return prober.estimate_batch(state.index, state.x, qs, taus, cfg,
                                      keys, pq_codes=state.pq.codes,
                                      pq_luts=luts, pq_resid=state.pq.resid,
@@ -184,7 +190,7 @@ def estimate_batch_pooled(state: ProberState, qs: jax.Array, taus: jax.Array,
     keys = jax.random.split(key, qs.shape[0])
     axis_name = axis_name if isinstance(axis_name, str) else tuple(axis_name)
     if cfg.use_pq and state.pq is not None:
-        luts = jax.vmap(lambda q: pqmod.build_query_lut(state.pq, q, cfg))(qs)
+        luts = _query_luts(state.pq, qs, cfg)
         return prober.estimate_batch(state.index, state.x, qs, taus, cfg,
                                      keys, pq_codes=state.pq.codes,
                                      pq_luts=luts, pq_resid=state.pq.resid,

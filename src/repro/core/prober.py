@@ -122,6 +122,7 @@ def _prp_eval(idx: jax.Array, rks: jax.Array, mask: jax.Array,
     return x.astype(jnp.int32)
 
 
+@jax.named_scope("probe/central")
 def _count_central(view: TableView, cum0: jax.Array, qualfn: QualFn,
                    cfg: ProberConfig):
     """Alg. 3: exact brute-force count inside B_central.
@@ -160,24 +161,25 @@ def _table_setup(view: TableView, qcode: jax.Array, central_qualfn: QualFn,
     """Loop-free ring construction for one (query, table) lane (DESIGN.md
     §9): the batched Hamming compare, ONE cumsum covering every ring, the
     exact central count (Alg. 3) and the per-ring PRP domains / Chernoff
-    schedule anchors. Returns ``(ctx, est0, visited0)``."""
-    ham = lsh.hamming_to_buckets(view.bucket_codes, view.n_buckets, qcode)
-    n_rings = view.bucket_codes.shape[-1]
-    cums = ring_cumsums(view, ham, n_rings)                    # (K+1, B)
-    rks = jax.random.bits(key, (6,), jnp.uint32)   # PRP round keys, Alg. 2
+    schedule anchors. Returns ``(ctx, est0, visited0)``. Its ops carry the
+    device scopes ``probe/rings`` and ``probe/central``."""
+    with jax.named_scope("probe/rings"):
+        ham = lsh.hamming_to_buckets(view.bucket_codes, view.n_buckets, qcode)
+        n_rings = view.bucket_codes.shape[-1]
+        cums = ring_cumsums(view, ham, n_rings)                # (K+1, B)
+        rks = jax.random.bits(key, (6,), jnp.uint32)   # PRP round keys, Alg. 2
+        totals = cums[1:, -1]                                  # (K,) |N_k|
+        totals_f = totals.astype(jnp.float32)
+        caps = jnp.minimum(totals, cfg.ring_budget)
+        # per-ring PRP domain: P_k = 2^{nbits_k} = next_pow2(cap_k)
+        nbits = jnp.where(caps <= 1, 0,
+                          32 - jax.lax.clz(jnp.maximum(caps - 1, 1)))
+        prings = jnp.left_shift(1, nbits)                      # (K,)
+        # schedule anchors per ring (Alg. 2 line 8): w_1 = ceil(s1 * |N_k|)
+        w_caps = jnp.minimum(jnp.ceil(cfg.s_max * totals_f),
+                             caps.astype(jnp.float32))
+        first_targets = jnp.maximum(jnp.ceil(cfg.s1 * totals_f), 1.0)
     est0, visited0 = _count_central(view, cums[0], central_qualfn, cfg)
-
-    totals = cums[1:, -1]                                      # (K,) |N_k|
-    totals_f = totals.astype(jnp.float32)
-    caps = jnp.minimum(totals, cfg.ring_budget)
-    # per-ring PRP domain: P_k = 2^{nbits_k} = next_pow2(cap_k)
-    nbits = jnp.where(caps <= 1, 0,
-                      32 - jax.lax.clz(jnp.maximum(caps - 1, 1)))
-    prings = jnp.left_shift(1, nbits)                          # (K,)
-    # schedule anchors per ring (Alg. 2 line 8): w_1 = ceil(s1 * |N_k|)
-    w_caps = jnp.minimum(jnp.ceil(cfg.s_max * totals_f),
-                         caps.astype(jnp.float32))
-    first_targets = jnp.maximum(jnp.ceil(cfg.s1 * totals_f), 1.0)
     ctx = LaneCtx(cums=cums, rks=rks, prings=prings, caps=caps, nbits=nbits,
                   totals_f=totals_f, w_caps=w_caps,
                   first_targets=first_targets,
@@ -204,6 +206,7 @@ def _make_ring_fn(qualfn: QualFn, exact_qualfn: QualFn | None,
     return lambda k, ids: qualfn(ids)
 
 
+@jax.named_scope("probe/slab")
 def _slab_step(s, ctx: LaneCtx, get_cum, get_starts, get_order, ring_fn,
                cfg: ProberConfig, n_buckets: int, n_points: int,
                n_rings: int, axis_name=None):
@@ -223,7 +226,8 @@ def _slab_step(s, ctx: LaneCtx, get_cum, get_starts, get_order, ring_fn,
     advances at ring completion, so checking it by itself could not fire
     mid-ring and overshot ``max_visit`` by up to a whole ring (bugfix, this
     PR). A budget hit forces ring completion, so the partial ring's
-    (unbiased) estimate is still folded into the total.
+    (unbiased) estimate is still folded into the total. Its ops carry the
+    device scope ``probe/slab``.
     """
     chunk = cfg.chunk
     slot_iota = jnp.arange(chunk, dtype=jnp.int32)
@@ -387,7 +391,8 @@ def _run_one_table(view: TableView, qcode: jax.Array, qualfn: QualFn,
                           axis_name=axis_name)
 
     init = _init_state(ctx, est0, visited0, n_rings)
-    return jax.lax.while_loop(lambda s: ~s["done"], body, init)
+    with jax.named_scope("probe/slab"):     # the loop's own control too
+        return jax.lax.while_loop(lambda s: ~s["done"], body, init)
 
 
 def make_exact_qualfn(x: jax.Array, q: jax.Array, tau_sq: jax.Array,
@@ -573,7 +578,8 @@ def _estimate_batch_compact(index: lsh.LSHIndex, x: jax.Array, qs: jax.Array,
     (in-loop psum, DESIGN.md §4) keeps the monolithic lockstep loop —
     :func:`estimate_batch` routes ``axis_name`` calls there.
     """
-    qcodes = lsh.hash_point(index.params, qs, index.n_tables)   # (Q, L, K)
+    with jax.named_scope("probe/prep"):
+        qcodes = lsh.hash_point(index.params, qs, index.n_tables)  # (Q, L, K)
     views = table_views(index)
     use_pq = pq_codes is not None and pq_luts is not None
     nq = qs.shape[0]
@@ -749,7 +755,8 @@ def estimate_batch(index: lsh.LSHIndex, x: jax.Array, qs: jax.Array,
                                        pq_resid=pq_resid,
                                        pq_packed=pq_packed,
                                        with_stats=with_stats)
-    qcodes = lsh.hash_point(index.params, qs, index.n_tables)   # (Q, L, K)
+    with jax.named_scope("probe/prep"):
+        qcodes = lsh.hash_point(index.params, qs, index.n_tables)  # (Q, L, K)
     views = table_views(index)
     use_pq = pq_codes is not None and pq_luts is not None
 

@@ -21,6 +21,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.cache import estimate_cache as C
 from repro.core import estimator as E, lsh, updates
@@ -97,6 +98,18 @@ class CardinalityCoalescer:
     only — the cache keys on this process's index geometry. Per-request
     provenance lands in :class:`CardRequest`/:class:`CardResult`; hit /
     miss / stale / evict counters accumulate in :attr:`cache_stats`.
+
+    Instrumentation: every flush runs inside host spans named ``coal/*``
+    (``jax.profiler.TraceAnnotation``, on the same clock as the device's
+    trace; inert unless a profile is being recorded): ``coal/flush``
+    holds ``coal/ingest``, ``coal/pack`` (padding, host-to-device
+    copies, the flush's key), ``coal/lookup``, ``coal/probe``,
+    ``coal/insert`` and ``coal/merge``, and every blocking device-to-host
+    read sits in a ``coal/sync`` span inside the phase that makes it.
+    :attr:`stats` counts the work: ``flushes`` (batches stepped),
+    ``probe_lanes`` / ``probe_live`` (lanes sent to the prober, padding
+    included / live ones among them) and ``syncs`` (device-to-host reads
+    while flushing).
     """
 
     def __init__(self, state: E.ProberState, cfg: ProberConfig,
@@ -115,6 +128,8 @@ class CardinalityCoalescer:
             if cache_size > 0 else None
         self.cache_stats = {"hits": 0, "misses": 0, "stale": 0, "evicts": 0,
                             "lookups": 0}
+        self.stats = dict.fromkeys(
+            ("flushes", "probe_lanes", "probe_live", "syncs"), 0)
         # host-tracked: False until the first ingest (or external state
         # swap) — lets lookup() statically elide the ball-sum recompute
         # while the corpus is provably unchanged (repro/cache/epochs.py)
@@ -199,15 +214,23 @@ class CardinalityCoalescer:
         buf = self._ingest_buf
         part, rest = buf[:k], buf[k:]
         self._ingest_buf = rest if len(rest) else None
-        if self.mesh is not None:
-            from repro.core import distributed as D
-            self._state, self._n_valid = D.update_sharded(
-                self._state, part, self.cfg, self.mesh,
-                data_axes=self.data_axes, n_valid=self._n_valid)
-            return
-        self._state = E.update(self._state, jnp.asarray(part), self.cfg,
-                               n_valid=self._n_valid)
-        self._n_valid += len(part)
+        with TraceAnnotation("coal/ingest"):
+            if self.mesh is not None:
+                from repro.core import distributed as D
+                self._state, self._n_valid = D.update_sharded(
+                    self._state, part, self.cfg, self.mesh,
+                    data_axes=self.data_axes, n_valid=self._n_valid)
+                return
+            self._state = E.update(self._state, jnp.asarray(part), self.cfg,
+                                   n_valid=self._n_valid)
+            self._n_valid += len(part)
+
+    def _read(self, x) -> np.ndarray:
+        """A blocking device-to-host read made while flushing: counted in
+        ``stats["syncs"]`` and marked by a ``coal/sync`` span."""
+        self.stats["syncs"] += 1
+        with TraceAnnotation("coal/sync"):
+            return np.asarray(x)
 
     def flush(self) -> dict[int, float]:
         """Apply pending ingests, then run jitted estimate_batch steps
@@ -224,66 +247,78 @@ class CardinalityCoalescer:
         return out
 
     def _drain(self) -> dict[int, float]:
-        self.apply_ingest()          # estimates see every prior ingest()
         out: dict[int, float] = {}
-        while self.pending:
-            batch, self.pending = self.pending[:self.max_batch], \
-                self.pending[self.max_batch:]
-            n = len(batch)
-            p = updates.next_pow2(n)
-            d = batch[0].q.shape[-1]
-            qs = np.zeros((p, d), np.float32)
-            taus = np.zeros((p,), np.float32)
-            for i, r in enumerate(batch):
-                qs[i], taus[i] = r.q, r.tau
-            key = jax.random.fold_in(self.key, self._n_flushes)
-            self._n_flushes += 1
-            if self._cache is not None:
-                ests, prov, pks, nvs = self._flush_cached(qs, taus, n, key)
-                for i, r in enumerate(batch):
-                    r.probed_k, r.nvisited = pks[i], nvs[i]
-            elif self.mesh is not None:
-                from repro.core import distributed as D
-                ests = np.asarray(D.estimate_sharded(
-                    self.state, jnp.asarray(qs), jnp.asarray(taus),
-                    self.cfg, key, self.mesh, data_axes=self.data_axes,
-                    mode=self.mode))
-                prov = ["probe"] * n
-            else:
-                ests = np.asarray(E.estimate_batch(
-                    self.state, jnp.asarray(qs), jnp.asarray(taus),
-                    self.cfg, key))
-                prov = ["probe"] * n
-            for i, r in enumerate(batch):
-                r.est = float(ests[i])
-                r.provenance = prov[i]
-                out[r.rid] = CardResult(r.est, prov[i])
+        if not self.pending and self._ingest_buf is None:
+            return out
+        with TraceAnnotation("coal/flush"):
+            self.apply_ingest()      # estimates see every prior ingest()
+            while self.pending:
+                batch, self.pending = self.pending[:self.max_batch], \
+                    self.pending[self.max_batch:]
+                n = len(batch)
+                p = updates.next_pow2(n)
+                with TraceAnnotation("coal/pack"):
+                    d = batch[0].q.shape[-1]
+                    qs = np.zeros((p, d), np.float32)
+                    taus = np.zeros((p,), np.float32)
+                    for i, r in enumerate(batch):
+                        qs[i], taus[i] = r.q, r.tau
+                    jqs, jtaus = jnp.asarray(qs), jnp.asarray(taus)
+                    key = jax.random.fold_in(self.key, self._n_flushes)
+                self._n_flushes += 1
+                self.stats["flushes"] += 1
+                if self._cache is not None:
+                    ests, prov, pks, nvs = self._flush_cached(
+                        qs, taus, jqs, jtaus, n, key)
+                else:
+                    self.stats["probe_lanes"] += p
+                    self.stats["probe_live"] += n
+                    with TraceAnnotation("coal/probe"):
+                        if self.mesh is not None:
+                            from repro.core import distributed as D
+                            ests = self._read(D.estimate_sharded(
+                                self.state, jqs, jtaus, self.cfg, key,
+                                self.mesh, data_axes=self.data_axes,
+                                mode=self.mode))
+                        else:
+                            ests = self._read(E.estimate_batch(
+                                self.state, jqs, jtaus, self.cfg, key))
+                    prov = ["probe"] * n
+                    pks = nvs = [None] * n
+                with TraceAnnotation("coal/merge"):
+                    for i, r in enumerate(batch):
+                        r.est = float(ests[i])
+                        r.provenance = prov[i]
+                        r.probed_k, r.nvisited = pks[i], nvs[i]
+                        out[r.rid] = CardResult(r.est, prov[i])
         return out
 
-    def _flush_cached(self, qs: np.ndarray, taus: np.ndarray, n: int,
+    def _flush_cached(self, qs: np.ndarray, taus: np.ndarray,
+                      jqs: jax.Array, jtaus: jax.Array, n: int,
                       key: jax.Array):
         """One flush through the estimate cache (DESIGN.md §12): look every
         request up, probe ONLY the miss lanes (padded to a power of two so
         the §11 compacting scheduler sees at most log2(max_batch) batch
         shapes), write fresh results back with their epoch snapshots, and
-        merge. Returns ``(ests (n,), provenance (n,), probed_k (n,),
-        nvisited (n,))`` — the latter two per-request audit stats (None
-        for hits, whose rings were set by the entry's original probe)."""
+        merge. ``jqs``/``jtaus`` are ``qs``/``taus`` on the device.
+        Returns ``(ests (n,), provenance (n,), probed_k (n,), nvisited
+        (n,))`` — the latter two per-request audit stats (None for hits,
+        whose rings were set by the entry's original probe)."""
         st = self._state
         strict = self.reuse_tol <= 0.0
-        jqs = jnp.asarray(qs)
-        qcodes = self._hash(st.index.params, jqs)
-        qhash = C.query_hash(jqs)
-        tkeys = C.tau_band(jnp.asarray(taus), self.reuse_tol)
-        live = jnp.arange(qs.shape[0]) < n
-        self._cache, c_est, hit, stale = C.lookup(
-            self._cache, st.epochs, st.index.bucket_codes,
-            st.index.bucket_sizes, st.index.n_buckets, qcodes, qhash,
-            tkeys, live, match_qhash=strict,
-            check_ingest=self._check_ingest)
-        hit = np.asarray(hit)[:n]
-        stale = np.asarray(stale)[:n]
-        ests = np.asarray(c_est)[:n].copy()
+        with TraceAnnotation("coal/lookup"):
+            qcodes = self._hash(st.index.params, jqs)
+            qhash = C.query_hash(jqs)
+            tkeys = C.tau_band(jtaus, self.reuse_tol)
+            live = jnp.arange(qs.shape[0]) < n
+            self._cache, c_est, hit, stale = C.lookup(
+                self._cache, st.epochs, st.index.bucket_codes,
+                st.index.bucket_sizes, st.index.n_buckets, qcodes, qhash,
+                tkeys, live, match_qhash=strict,
+                check_ingest=self._check_ingest)
+            hit = self._read(hit)[:n]
+            stale = self._read(stale)[:n]
+            ests = self._read(c_est)[:n].copy()
         miss = np.nonzero(~hit)[0]
         self.cache_stats["lookups"] += n
         self.cache_stats["hits"] += int(hit.sum())
@@ -296,27 +331,33 @@ class CardinalityCoalescer:
         nvs: list = [None] * n
         if len(miss):
             pm = updates.next_pow2(len(miss))
-            qs_m = np.zeros((pm, qs.shape[1]), np.float32)
-            taus_m = np.zeros((pm,), np.float32)
-            qs_m[:len(miss)], taus_m[:len(miss)] = qs[miss], taus[miss]
-            jqs_m, jtaus_m = jnp.asarray(qs_m), jnp.asarray(taus_m)
-            ests_m, probed_k, nvis = E.estimate_batch_stats(
-                st, jqs_m, jtaus_m, self.cfg, key)
-            active = jnp.arange(pm) < len(miss)
-            # keys for the write-back: gather the rows already computed for
-            # the full-batch lookup (no second hash matmul / fingerprint
-            # pass); rows past len(miss) are padding and inactive
-            mrows = jnp.asarray(np.pad(miss, (0, pm - len(miss))))
-            self._cache, n_evict = C.insert(
-                self._cache, st.epochs, st.index.bucket_codes,
-                st.index.bucket_sizes, st.index.n_buckets,
-                qcodes[mrows], qhash[mrows], tkeys[mrows],
-                ests_m, nvis, probed_k, active, match_qhash=strict)
-            self.cache_stats["evicts"] += int(n_evict)
-            ests[miss] = np.asarray(ests_m)[:len(miss)]
-            pk_np, nv_np = np.asarray(probed_k), np.asarray(nvis)
-            for j, i in enumerate(miss):
-                pks[i], nvs[i] = pk_np[j], int(nv_np[j])
+            self.stats["probe_lanes"] += pm
+            self.stats["probe_live"] += len(miss)
+            with TraceAnnotation("coal/probe"):
+                qs_m = np.zeros((pm, qs.shape[1]), np.float32)
+                taus_m = np.zeros((pm,), np.float32)
+                qs_m[:len(miss)], taus_m[:len(miss)] = qs[miss], taus[miss]
+                jqs_m, jtaus_m = jnp.asarray(qs_m), jnp.asarray(taus_m)
+                ests_m, probed_k, nvis = E.estimate_batch_stats(
+                    st, jqs_m, jtaus_m, self.cfg, key)
+            with TraceAnnotation("coal/insert"):
+                active = jnp.arange(pm) < len(miss)
+                # keys for the write-back: gather the rows already computed
+                # for the full-batch lookup (no second hash matmul /
+                # fingerprint pass); rows past len(miss) are padding and
+                # inactive
+                mrows = jnp.asarray(np.pad(miss, (0, pm - len(miss))))
+                self._cache, n_evict = C.insert(
+                    self._cache, st.epochs, st.index.bucket_codes,
+                    st.index.bucket_sizes, st.index.n_buckets,
+                    qcodes[mrows], qhash[mrows], tkeys[mrows],
+                    ests_m, nvis, probed_k, active, match_qhash=strict)
+                self.cache_stats["evicts"] += int(self._read(n_evict))
+            with TraceAnnotation("coal/merge"):
+                ests[miss] = self._read(ests_m)[:len(miss)]
+                pk_np, nv_np = self._read(probed_k), self._read(nvis)
+                for j, i in enumerate(miss):
+                    pks[i], nvs[i] = pk_np[j], int(nv_np[j])
         return ests, prov, pks, nvs
 
 
