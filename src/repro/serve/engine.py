@@ -16,6 +16,7 @@ instead of being re-dispatched per query.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -59,13 +60,57 @@ class CardResult(float):
         return self
 
 
+@partial(jax.jit, static_argnames=("n_tables", "reuse_tol", "match_qhash",
+                                   "check_ingest"), donate_argnames=("cache",))
+def _lookup_step(cache: C.EstimateCache, epochs, params, bucket_codes,
+                 bucket_sizes, n_buckets, qs: jax.Array, taus: jax.Array,
+                 n: jax.Array, *, n_tables: int, reuse_tol: float,
+                 match_qhash: bool, check_ingest: bool):
+    """The cached flush's lookup phase as one program: the (p, L, K) bucket
+    codes, the query fingerprints and tau keys of the padded batch, the
+    live mask of its first ``n`` rows, and ``C.lookup``. Returns ``(cache',
+    keys, (hit, stale, est))``; ``keys`` stays on the device for
+    :func:`_insert_step`. ``cache`` is donated, as in :func:`_insert_step`:
+    the cache returned reuses its buffers, so a call allocates no device
+    memory for the cache's arrays."""
+    qcodes = lsh.hash_point(params, qs, n_tables)
+    keys = (qcodes, C.query_hash(qs), C.tau_band(taus, reuse_tol))
+    live = jnp.arange(qs.shape[0]) < n
+    cache, est, hit, stale = C.lookup(
+        cache, epochs, bucket_codes, bucket_sizes, n_buckets, *keys, live,
+        match_qhash=match_qhash, check_ingest=check_ingest)
+    return cache, keys, (hit, stale, est)
+
+
+@partial(jax.jit, static_argnames=("match_qhash",),
+         donate_argnames=("cache",))
+def _insert_step(cache: C.EstimateCache, epochs, bucket_codes, bucket_sizes,
+                 n_buckets, keys, mrows: jax.Array, n_miss: jax.Array,
+                 ests: jax.Array, probed_k: jax.Array, nvisited: jax.Array,
+                 *, match_qhash: bool):
+    """The cached flush's write-back as one program: gather the lookup's
+    keys at the miss rows ``mrows`` (padded to the probe's pm lanes; rows
+    past ``n_miss`` are inactive) and ``C.insert`` the probe's results.
+    Returns ``(cache', n_evicted)``."""
+    qcodes, qhash, tkeys = (k[mrows] for k in keys)
+    active = jnp.arange(mrows.shape[0]) < n_miss
+    return C.insert(cache, epochs, bucket_codes, bucket_sizes, n_buckets,
+                    qcodes, qhash, tkeys, ests, nvisited, probed_k, active,
+                    match_qhash=match_qhash)
+
+
 class CardinalityCoalescer:
     """Coalesces concurrent cardinality requests into one jitted step.
 
     ``submit`` enqueues; ``flush`` pads the pending batch up to the next
     power of two (so at most ``log2(max_batch) + 1`` batch shapes ever
     compile), runs a single ``estimate_batch`` over all of it, and returns
-    ``{rid: estimate}``. Flush ``i`` derives its PRNG key as
+    ``{rid: estimate}``. With the estimate cache the lookup step compiles
+    once per batch shape p as well, and the write-back step once per pair
+    of p and the padded miss count pm <= p: at most 10 pairs at
+    ``max_batch`` 8, and a flush whose lanes all miss uses (p, p). The
+    first flush after the first ingest compiles each lookup shape once
+    more (its ingest re-check is static). Flush ``i`` derives its PRNG key as
     ``jax.random.fold_in(key, i)``, making a request's estimate a pure
     function of (key, flush index, position in batch) — deterministic and
     replayable for audit.
@@ -97,7 +142,11 @@ class CardinalityCoalescer:
     near-duplicate matching (see repro/cache). Local (unsharded) serving
     only — the cache keys on this process's index geometry. Per-request
     provenance lands in :class:`CardRequest`/:class:`CardResult`; hit /
-    miss / stale / evict counters accumulate in :attr:`cache_stats`.
+    miss / stale / evict counters accumulate in :attr:`cache_stats`. The
+    lookup phase (hash, fingerprint, tau key, live mask, ``C.lookup``) is
+    one jitted program (:func:`_lookup_step`) and the write-back (key
+    gathers at the miss rows, ``C.insert``) another
+    (:func:`_insert_step`).
 
     Instrumentation: every flush runs inside host spans named ``coal/*``
     (``jax.profiler.TraceAnnotation``, on the same clock as the device's
@@ -109,7 +158,8 @@ class CardinalityCoalescer:
     :attr:`stats` counts the work: ``flushes`` (batches stepped),
     ``probe_lanes`` / ``probe_live`` (lanes sent to the prober, padding
     included / live ones among them) and ``syncs`` (device-to-host reads
-    while flushing).
+    while flushing: one per uncached batch; per cached batch one after
+    the lookup, and one more in ``coal/merge`` when it probes).
     """
 
     def __init__(self, state: E.ProberState, cfg: ProberConfig,
@@ -134,8 +184,6 @@ class CardinalityCoalescer:
         # swap) — lets lookup() statically elide the ball-sum recompute
         # while the corpus is provably unchanged (repro/cache/epochs.py)
         self._check_ingest = False
-        self._hash = jax.jit(
-            lambda params, qs: lsh.hash_point(params, qs, cfg.n_tables))
         self.state = state              # property: also syncs _n_valid
         self._check_ingest = False      # the swap bump above is moot while
                                         # the cache is still empty
@@ -225,12 +273,13 @@ class CardinalityCoalescer:
                                    n_valid=self._n_valid)
             self._n_valid += len(part)
 
-    def _read(self, x) -> np.ndarray:
+    def _read(self, x):
         """A blocking device-to-host read made while flushing: counted in
-        ``stats["syncs"]`` and marked by a ``coal/sync`` span."""
+        ``stats["syncs"]`` and marked by a ``coal/sync`` span. ``x`` may be
+        a tuple of arrays, fetched together as one read."""
         self.stats["syncs"] += 1
         with TraceAnnotation("coal/sync"):
-            return np.asarray(x)
+            return jax.device_get(x)
 
     def flush(self) -> dict[int, float]:
         """Apply pending ingests, then run jitted estimate_batch steps
@@ -305,20 +354,16 @@ class CardinalityCoalescer:
         (n,))`` — the latter two per-request audit stats (None for hits,
         whose rings were set by the entry's original probe)."""
         st = self._state
+        idx = st.index
         strict = self.reuse_tol <= 0.0
         with TraceAnnotation("coal/lookup"):
-            qcodes = self._hash(st.index.params, jqs)
-            qhash = C.query_hash(jqs)
-            tkeys = C.tau_band(jtaus, self.reuse_tol)
-            live = jnp.arange(qs.shape[0]) < n
-            self._cache, c_est, hit, stale = C.lookup(
-                self._cache, st.epochs, st.index.bucket_codes,
-                st.index.bucket_sizes, st.index.n_buckets, qcodes, qhash,
-                tkeys, live, match_qhash=strict,
-                check_ingest=self._check_ingest)
-            hit = self._read(hit)[:n]
-            stale = self._read(stale)[:n]
-            ests = self._read(c_est)[:n].copy()
+            self._cache, keys, looked = _lookup_step(
+                self._cache, st.epochs, idx.params, idx.bucket_codes,
+                idx.bucket_sizes, idx.n_buckets, jqs, jtaus, n,
+                n_tables=self.cfg.n_tables, reuse_tol=self.reuse_tol,
+                match_qhash=strict, check_ingest=self._check_ingest)
+            hit, stale, ests = (a[:n] for a in self._read(looked))
+            ests = ests.copy()
         miss = np.nonzero(~hit)[0]
         self.cache_stats["lookups"] += n
         self.cache_stats["hits"] += int(hit.sum())
@@ -341,21 +386,19 @@ class CardinalityCoalescer:
                 ests_m, probed_k, nvis = E.estimate_batch_stats(
                     st, jqs_m, jtaus_m, self.cfg, key)
             with TraceAnnotation("coal/insert"):
-                active = jnp.arange(pm) < len(miss)
-                # keys for the write-back: gather the rows already computed
-                # for the full-batch lookup (no second hash matmul /
-                # fingerprint pass); rows past len(miss) are padding and
-                # inactive
-                mrows = jnp.asarray(np.pad(miss, (0, pm - len(miss))))
-                self._cache, n_evict = C.insert(
-                    self._cache, st.epochs, st.index.bucket_codes,
-                    st.index.bucket_sizes, st.index.n_buckets,
-                    qcodes[mrows], qhash[mrows], tkeys[mrows],
-                    ests_m, nvis, probed_k, active, match_qhash=strict)
-                self.cache_stats["evicts"] += int(self._read(n_evict))
+                # keys for the write-back: the rows already computed for
+                # the full-batch lookup (no second hash matmul or
+                # fingerprint pass); rows past len(miss) are padding
+                mrows = np.pad(miss, (0, pm - len(miss))).astype(np.int32)
+                self._cache, n_evict = _insert_step(
+                    self._cache, st.epochs, idx.bucket_codes,
+                    idx.bucket_sizes, idx.n_buckets, keys, mrows, len(miss),
+                    ests_m, probed_k, nvis, match_qhash=strict)
             with TraceAnnotation("coal/merge"):
-                ests[miss] = self._read(ests_m)[:len(miss)]
-                pk_np, nv_np = self._read(probed_k), self._read(nvis)
+                n_evict, ests_m, pk_np, nv_np = self._read(
+                    (n_evict, ests_m, probed_k, nvis))
+                self.cache_stats["evicts"] += int(n_evict)
+                ests[miss] = ests_m[:len(miss)]
                 for j, i in enumerate(miss):
                     pks[i], nvs[i] = pk_np[j], int(nv_np[j])
         return ests, prov, pks, nvs

@@ -6,8 +6,9 @@ forces a re-probe and NO stale hit is ever served (checked against an
 exact shadow tracker over a mixed ingest+query stream, including across
 capacity-doubling growth); `reuse_tol` bands tau and relaxes the exact-
 query fingerprint; CLOCK eviction prefers cold entries; repeated all-hit
-flushes add zero XLA compilations; and flush() reports per-request
-provenance.
+flushes add zero XLA compilations; the coalescer's jitted lookup and
+write-back steps give, bit for bit, what the eager library calls give;
+and flush() reports per-request provenance.
 """
 import jax
 import jax.numpy as jnp
@@ -15,9 +16,12 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 from conftest import compile_events
+from jax import monitoring
 
+from repro.cache import estimate_cache as C
 from repro.core import estimator as E, lsh
 from repro.core.config import ProberConfig
+from repro.core.updates import next_pow2
 from repro.serve.engine import CardinalityCoalescer
 
 CFG = ProberConfig(n_tables=2, n_funcs=6, ring_budget=512,
@@ -317,3 +321,150 @@ def test_property_repeat_hit_equals_first_serve(idx, tau):
     co.flush()
     assert r0.provenance == "probe" and r1.provenance == "hit"
     assert r0.est == r1.est
+
+
+class _EagerCoalescer(CardinalityCoalescer):
+    """Shadow of the cached flush as the library calls compose it eagerly:
+    the hash, ``C.query_hash``, ``C.tau_band``, the live mask and
+    ``C.lookup`` as separate dispatches, the probe, then the key gathers
+    and ``C.insert``, with the same key. The coalescer's jitted lookup and
+    write-back steps must reproduce it bit for bit."""
+
+    def _flush_cached(self, qs, taus, jqs, jtaus, n, key):
+        st_ = self._state
+        strict = self.reuse_tol <= 0.0
+        qcodes = lsh.hash_point(st_.index.params, jqs, self.cfg.n_tables)
+        qhash = C.query_hash(jqs)
+        tkeys = C.tau_band(jtaus, self.reuse_tol)
+        live = jnp.arange(qs.shape[0]) < n
+        self._cache, c_est, hit, stale = C.lookup(
+            self._cache, st_.epochs, st_.index.bucket_codes,
+            st_.index.bucket_sizes, st_.index.n_buckets, qcodes, qhash,
+            tkeys, live, match_qhash=strict,
+            check_ingest=self._check_ingest)
+        hit, stale = np.asarray(hit)[:n], np.asarray(stale)[:n]
+        ests = np.asarray(c_est)[:n].copy()
+        miss = np.nonzero(~hit)[0]
+        self.cache_stats["lookups"] += n
+        self.cache_stats["hits"] += int(hit.sum())
+        self.cache_stats["misses"] += len(miss)
+        self.cache_stats["stale"] += int(stale.sum())
+        prov = ["hit" if hit[i] else
+                ("stale-refresh" if stale[i] else "probe")
+                for i in range(n)]
+        pks: list = [None] * n
+        nvs: list = [None] * n
+        if len(miss):
+            pm = next_pow2(len(miss))
+            qs_m = np.zeros((pm, qs.shape[1]), np.float32)
+            taus_m = np.zeros((pm,), np.float32)
+            qs_m[:len(miss)], taus_m[:len(miss)] = qs[miss], taus[miss]
+            ests_m, probed_k, nvis = E.estimate_batch_stats(
+                st_, jnp.asarray(qs_m), jnp.asarray(taus_m), self.cfg, key)
+            mrows = jnp.asarray(np.pad(miss, (0, pm - len(miss))))
+            self._cache, n_evict = C.insert(
+                self._cache, st_.epochs, st_.index.bucket_codes,
+                st_.index.bucket_sizes, st_.index.n_buckets,
+                qcodes[mrows], qhash[mrows], tkeys[mrows], ests_m, nvis,
+                probed_k, jnp.arange(pm) < len(miss), match_qhash=strict)
+            self.cache_stats["evicts"] += int(n_evict)
+            ests[miss] = np.asarray(ests_m)[:len(miss)]
+            pk_np, nv_np = np.asarray(probed_k), np.asarray(nvis)
+            for j, i in enumerate(miss):
+                pks[i], nvs[i] = pk_np[j], int(nv_np[j])
+        return ests, prov, pks, nvs
+
+
+@pytest.mark.parametrize("reuse_tol", [0.0, 0.25])
+def test_jitted_steps_match_eager_shadow(data, reuse_tol):
+    """Same submits, ingests and flushes into the coalescer and its eager
+    shadow: every estimate, provenance, probed ring depth and sample count,
+    the cache counters and the final cache arrays are bit-identical. The
+    stream holds repeats, near-repeats (one float bit off, tau 1% off:
+    hits only under ``reuse_tol`` > 0), fresh queries, CLOCK evictions
+    and an ingest next to a cached query, after which that query is
+    refreshed as stale. One request has the key of a padding lane (a zero
+    query at tau 0): padding lanes of later flushes must not touch it."""
+    cfg = CFG.replace(ingest_chunk=64)
+    key = jax.random.PRNGKey(5)
+    st_ = E.build(jnp.asarray(data[:1024]), cfg, key, capacity=4096,
+                  track_epochs=True)
+    cos = [cls(st_, cfg, key, max_batch=8, cache_size=8,
+               reuse_tol=reuse_tol)
+           for cls in (CardinalityCoalescer, _EagerCoalescer)]
+    qs = [data[i] + 0.01 for i in range(14)]
+    near = qs[2].copy()
+    near[0] = np.nextafter(near[0], np.inf)
+    cluster = (qs[0][None, :] + 0.05 * np.asarray(jax.random.normal(
+        jax.random.PRNGKey(1), (64, 16)))).astype(np.float32)
+    stream = [
+        [(qs[i], t) for i, t in zip(range(5), (3.0, 4.0, 5.0, 3.5, 4.5))]
+        + [(np.zeros(16, np.float32), 0.0)],
+        [(qs[0], 3.0), (qs[1], 4.0), (near, 5.0 * 1.01), (qs[5], 4.0),
+         (qs[6], 4.0)],
+        [(qs[0], 3.0)] + [(qs[i], 4.0) for i in range(7, 12)],
+        "ingest",
+        [(qs[0], 3.0), (qs[1], 4.0), (qs[9], 4.0), (qs[12], 2.5)],
+        [(qs[0], 3.0), (qs[13], 4.0), (qs[3], 3.5), (qs[11], 4.0),
+         (qs[1], 4.0), (qs[2], 5.0), (qs[4], 4.5), (qs[5], 4.0)],
+    ]
+    for step in stream:
+        if step == "ingest":
+            for co in cos:
+                co.ingest(cluster)
+            continue
+        got = [[co.submit(q, t) for q, t in step] for co in cos]
+        for co in cos:
+            co.flush()
+        for a, b in zip(*got):
+            assert (a.est, a.provenance, a.nvisited) == \
+                (b.est, b.provenance, b.nvisited)
+            assert (a.probed_k is None) == (b.probed_k is None)
+            if a.probed_k is not None:
+                assert np.array_equal(a.probed_k, b.probed_k)
+    co, shadow = cos
+    assert co.cache_stats == shadow.cache_stats
+    for name, x, y in zip(C.EstimateCache._fields, co._cache,
+                          shadow._cache):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), name
+    cs = co.cache_stats
+    assert cs["hits"] > 0 and cs["stale"] > 0 and cs["evicts"] > 0, cs
+
+
+def test_cached_flush_compiles_one_program_per_phase():
+    """The first cached flush at a fresh shape (d = 40 and a 40-entry cache,
+    which no other test uses) compiles the lookup step, the probe, the
+    write-back step and, if this process has not yet, ``fold_in``: at
+    most 4 programs. With the eager lookup and write-back the same flush
+    compiled 32: the probe and ``fold_in``, and 30 for the hash, each op
+    of the fingerprint, the tau band and the masks, each key gather,
+    ``lookup`` and ``insert``. A second flush of the same shapes compiles
+    nothing."""
+    names: list = []
+
+    def named(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(kw.get("fun_name"))
+
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (600, 40)))
+    co = _coalescer(x, n=512, capacity=1024, cache_size=40)
+    jax.block_until_ready(co.state)
+    monitoring.register_event_duration_secs_listener(named)
+    try:
+        for rep in range(2):
+            names.clear()
+            with compile_events() as ev:
+                for i in range(3):
+                    co.submit(x[512 + 3 * rep + i], 4.0)
+                co.flush()
+            if rep == 0:
+                steps = {"jit(_lookup_step)", "jit(estimate_batch_stats)",
+                         "jit(_insert_step)"}
+                assert steps <= set(names) <= steps | {
+                    "jit(_threefry_fold_in)"}, names
+                assert len(ev) <= 4, ev
+            else:
+                assert ev == [] and names == [], names
+    finally:
+        monitoring.unregister_event_duration_listener(named)
+    assert co.cache_stats["misses"] == 6

@@ -44,9 +44,9 @@ def _delta(co, before):
 
 
 def test_syncs_per_cached_flush(data):
-    """An all-miss flush reads 7 arrays back (hit, stale, cached estimate,
-    evictions, estimates, rings, visits); an all-hit flush only the
-    lookup's 3."""
+    """An all-miss flush makes two reads: the lookup's (hit, stale,
+    cached estimate) together, then the merge's (evictions, estimates,
+    rings, visits) together. An all-hit flush makes only the lookup's."""
     co = _coalescer(data)
     qs = [data[i] + 0.01 for i in range(5)]
     before = dict(co.stats)
@@ -54,14 +54,14 @@ def test_syncs_per_cached_flush(data):
         co.submit(q, 4.0)
     co.flush()
     d = _delta(co, before)
-    assert (d["flushes"], d["syncs"]) == (1, 7)
+    assert (d["flushes"], d["syncs"]) == (1, 2)
     before = dict(co.stats)
     for q in qs:
         co.submit(q, 4.0)
     co.flush()
     d = _delta(co, before)
     assert co.cache_stats["hits"] == 5
-    assert (d["flushes"], d["syncs"], d["probe_lanes"]) == (1, 3, 0)
+    assert (d["flushes"], d["syncs"], d["probe_lanes"]) == (1, 1, 0)
 
 
 @pytest.mark.parametrize("n_hit,n_miss", [(0, 3), (2, 3), (1, 5), (4, 1)])

@@ -101,3 +101,44 @@ def test_served_flush_fits_one_chip(one_chip):
     for step in (E.estimate_batch, E.estimate_batch_stats):
         compiled = step.lower(*args, cfg, key).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+@pytest.mark.parametrize("check_ingest", [False, True])
+def test_cached_flush_steps_compile_for_one_chip(one_chip, check_ingest):
+    """The coalescer's lookup and write-back steps, as the benchmark's
+    sift1m cell serves them (capacity 2^20, a 1024-entry cache, flushes
+    of 8), compile for one chip, before and after the first ingest."""
+    from repro.cache import estimate_cache as C
+    from repro.serve import engine
+    cfg = serve_cfg(128)
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    state = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda x, k: E.attach_epochs(E.build(x, cfg, k, capacity=CAPACITY)),
+        jax.ShapeDtypeStruct((1_000_000, 128), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    cache = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda: C.init_cache(1024, cfg.n_tables, cfg.n_funcs)))
+    idx, q = state.index, chip_smoke.FLUSH
+    shape = lambda s, dt: place(jax.ShapeDtypeStruct(s, dt))  # noqa: E731
+    index_args = (idx.bucket_codes, idx.bucket_sizes, idx.n_buckets)
+    lookup = engine._lookup_step.lower(
+        cache, state.epochs, idx.params, *index_args,
+        shape((q, 128), jnp.float32), shape((q,), jnp.float32),
+        shape((), jnp.int32), n_tables=cfg.n_tables, reuse_tol=0.0,
+        match_qhash=True, check_ingest=check_ingest).compile()
+    _, keys, _ = jax.eval_shape(
+        lambda *a: engine._lookup_step(
+            *a, n_tables=cfg.n_tables, reuse_tol=0.0, match_qhash=True,
+            check_ingest=check_ingest),
+        cache, state.epochs, idx.params, *index_args,
+        shape((q, 128), jnp.float32), shape((q,), jnp.float32),
+        shape((), jnp.int32))
+    insert = engine._insert_step.lower(
+        cache, state.epochs, *index_args,
+        jax.tree_util.tree_map(place, keys), shape((q,), jnp.int32),
+        shape((), jnp.int32), shape((q,), jnp.float32),
+        shape((q, cfg.n_tables), jnp.int32), shape((q,), jnp.int32),
+        match_qhash=True).compile()
+    for compiled in (lookup, insert):
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
