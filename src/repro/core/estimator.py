@@ -56,6 +56,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.cache import epochs as cache_epochs
 from repro.core import lsh, pq as pqmod, prober, updates
@@ -85,6 +86,17 @@ class ProberState(NamedTuple):
         return self.x.shape[0]
 
 
+def stored_width(d: int) -> int:
+    """Columns of the capacity-padded corpus for published dimension ``d``:
+    ``d`` rounded up to a multiple of 128, the TPU's lane width. A corpus
+    whose width is not such a multiple gets a column-major default layout
+    on the chip, and the probe's row gathers would then copy the whole
+    corpus to row-major on every call; the zero columns past ``d`` add
+    exactly 0 to every squared distance, and the hash functions and PQ
+    subspaces stay on the ``d`` published dimensions."""
+    return -(-d // 128) * 128
+
+
 def build(x: jax.Array, cfg: ProberConfig, key: jax.Array,
           params: lsh.LSHParams | None = None,
           capacity: int | None = None,
@@ -92,23 +104,36 @@ def build(x: jax.Array, cfg: ProberConfig, key: jax.Array,
     """Offline build. With ``capacity`` (DESIGN.md §10) the state is
     capacity-padded: arrays sized to ``capacity`` rows with ``x.shape[0]``
     live, so subsequent :func:`update` calls that fit in the spare rows are
-    fixed-shape jitted steps that never recompile. ``track_epochs`` attaches
+    fixed-shape jitted steps that never recompile; the corpus is stored
+    :func:`stored_width` columns wide. ``track_epochs`` attaches
     the serving cache's ingest-epoch counters (DESIGN.md §12) so every
-    update also records which buckets it touched."""
+    update also records which buckets it touched. The phases run in the
+    host spans ``build/pad``, ``build/lsh``, ``build/pq_fit`` and
+    ``build/pq_encode`` (codes, counts and residuals)."""
     k1, k2 = jax.random.split(key)
     if capacity is None:
         index = lsh.build_index(x, cfg, k1, params=params)
         pq = pqmod.fit(x, cfg, k2) if cfg.use_pq else None
         state = ProberState(index=index, x=x, pq=pq)
     else:
-        n = x.shape[0]
+        n, d = x.shape
         assert capacity >= n, (capacity, n)
-        x_pad = jnp.pad(jnp.asarray(x, jnp.float32),
-                        ((0, capacity - n), (0, 0)))
-        index = lsh.build_index(x_pad, cfg, k1, params=params, n_valid=n)
+        # each phase ends on the device inside its span
+        with TraceAnnotation("build/pad"):
+            x_pad = jax.block_until_ready(jnp.pad(
+                jnp.asarray(x, jnp.float32),
+                ((0, capacity - n), (0, stored_width(d) - d))))
+        with TraceAnnotation("build/lsh"):
+            index = jax.block_until_ready(lsh.build_index(
+                x_pad, cfg, k1, params=params, n_valid=n, dim=d))
         pq = None
         if cfg.use_pq:
-            pq = pqmod.grow(pqmod.fit(x, cfg, k2), capacity)
+            with TraceAnnotation("build/pq_fit"):
+                centroids = jax.block_until_ready(
+                    pqmod.kmeans(x_pad, cfg, k2, n_valid=n, dim=d))
+            with TraceAnnotation("build/pq_encode"):
+                pq = jax.block_until_ready(
+                    pqmod.encode(x_pad, centroids, cfg, n_valid=n))
         state = ProberState(index=index, x=x_pad, pq=pq)
     return attach_epochs(state) if track_epochs else state
 
@@ -213,7 +238,8 @@ def _ingest_core(state: ProberState, x_pad: jax.Array, n_new: jax.Array,
     cache-invalidation signal rides the same zero-recompile step."""
     nv = state.index.n_valid
     old_w = state.index.params.w
-    x = updates._write_rows(state.x, x_pad, nv, n_new)
+    wide = jnp.pad(x_pad, ((0, 0), (0, state.x.shape[-1] - x_pad.shape[-1])))
+    x = updates._write_rows(state.x, wide, nv, n_new)
     index = updates._lsh_ingest(state.index, x_pad, n_new, cfg,
                                 axis_name=axis_name)
     pq = updates._pq_ingest(state.pq, x, x_pad, n_new) \
@@ -268,7 +294,9 @@ def update(state: ProberState, x_new: jax.Array, cfg: ProberConfig,
 def true_cardinality(x: jax.Array, q: jax.Array, tau: jax.Array,
                      n_valid: jax.Array | None = None) -> jax.Array:
     """Exact ground truth (for tests/benchmarks). ``n_valid`` masks the
-    capacity-padding rows of a padded corpus."""
+    capacity-padding rows of a padded corpus; ``x`` may be stored wider
+    than ``q`` (:func:`stored_width`)."""
+    q = jnp.pad(q, (0, x.shape[-1] - q.shape[-1]))
     d2 = jnp.sum((x - q[None, :]) ** 2, axis=-1)
     hit = d2 <= jnp.asarray(tau, jnp.float32) ** 2
     if n_valid is not None:

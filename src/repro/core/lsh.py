@@ -117,8 +117,15 @@ def project_raw(params: LSHParams, x: jax.Array) -> jax.Array:
     ``w``. This is what the index retains (``LSHIndex.raw``) and what
     Alg. 7's ``normalizeW`` reduces over: min/max of ``a·x`` are exactly
     reproducible across ingests, so ``W`` only moves when an extreme
-    actually moves (see module docstring)."""
-    return x.astype(jnp.float32) @ params.a
+    actually moves (see module docstring).
+
+    ``x`` may be wider than the d rows of ``a``: the stored corpus carries
+    zero columns past d (``estimator.stored_width``), which meet zero rows
+    of ``a`` and add nothing."""
+    a = params.a
+    if x.shape[-1] > a.shape[0]:
+        a = jnp.pad(a, ((0, x.shape[-1] - a.shape[0]), (0, 0)))
+    return x.astype(jnp.float32) @ a
 
 
 def normalize_w(raw: jax.Array, n_regions: int,
@@ -283,7 +290,8 @@ def _build_table(codes_t: jax.Array,
 
 def build_index(x: jax.Array, cfg: ProberConfig, key: jax.Array,
                 params: LSHParams | None = None,
-                n_valid: jax.Array | int | None = None) -> LSHIndex:
+                n_valid: jax.Array | int | None = None,
+                dim: int | None = None) -> LSHIndex:
     """Build the full L-table index over ``x`` (C, d).
 
     If ``params`` is given (distributed build / updates) the hash functions
@@ -294,10 +302,13 @@ def build_index(x: jax.Array, cfg: ProberConfig, key: jax.Array,
     their codes become ``CODE_SENTINEL``, and the bucket axis stays
     untrimmed (B = C) so the layout's shapes are a pure function of the
     capacity — the contract the jitted update steps rely on.
+
+    ``dim`` is the dimension the hash functions are drawn in when ``x``
+    carries zero columns past it (the stored layout, estimator.build).
     """
     nv = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
     if params is None:
-        params = init_params(key, x.shape[-1], cfg)
+        params = init_params(key, dim or x.shape[-1], cfg)
         raw = project_raw(params, x)                        # pure a·x
         params = params._replace(w=normalize_w(raw, cfg.n_regions, nv))
     else:

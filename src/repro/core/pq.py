@@ -71,92 +71,129 @@ def _assign_block(centroids: jax.Array, xs: jax.Array) -> jax.Array:
     return jnp.argmin(d2, axis=-1).astype(jnp.int32)
 
 
-def _row_blocks(a: jax.Array, rows: int, fill=0) -> jax.Array:
-    """(N, ...) -> (ceil(N/rows), rows, ...), the tail padded with ``fill``."""
-    pad = (-a.shape[0]) % rows
-    a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
-                constant_values=fill)
-    return a.reshape(-1, rows, *a.shape[1:])
-
-
 def _block_rows(n: int) -> int:
     return max(min(n, ASSIGN_ROWS), 1)
 
 
-def _subspace_blocks(xs: jax.Array, rows: int) -> jax.Array:
-    """(N, M, ds) -> (blocks, rows, M·ds) row blocks of the flat vectors.
+def _row_block(x: jax.Array, i, rows: int, width: int, n: int):
+    """Block ``i`` of ``rows`` rows of the first ``width`` columns of ``x``
+    (C, >= width), read in place: no padded copy of the corpus is made.
+    A block that would run past row C is read from row ``C - rows``, so
+    that it stays in bounds. ``own`` marks the rows that are block ``i``'s:
+    not the block before's, and below ``n``. Returns ``(start, block,
+    own)``."""
+    start = jnp.minimum(i * rows, x.shape[0] - rows)
+    block = jax.lax.dynamic_slice(x, (start, 0), (rows, x.shape[1]))
+    idx = start + jnp.arange(rows)
+    return start, block[:, :width], (idx >= i * rows) & (idx < n)
 
-    The blocks are cut from the (N, d) view and split per block: the TPU
-    pads the ds-wide minor dimension of a whole (N, M, ds) array to 128
-    lanes (16 GiB at N=1M, d=128), which a block-sized view never is."""
-    return _row_blocks(xs.reshape(xs.shape[0], -1), rows)
+
+def _put_rows(dst: jax.Array, start, new: jax.Array,
+              own: jax.Array) -> jax.Array:
+    """``dst`` with the owned rows of ``new`` written from row ``start``."""
+    at = (start,) + (0,) * (dst.ndim - 1)
+    old = jax.lax.dynamic_slice(dst, at, new.shape)
+    own = own.reshape(own.shape + (1,) * (new.ndim - 1))
+    return jax.lax.dynamic_update_slice(dst, jnp.where(own, new, old), at)
 
 
-def assign(centroids: jax.Array, xs: jax.Array) -> jax.Array:
-    """Nearest-centroid assignment per subspace. xs: (N, M, ds) -> (N, M).
+def assign(centroids: jax.Array, x: jax.Array,
+           n: int | None = None) -> jax.Array:
+    """Nearest-centroid assignment per subspace: (C, M) int32 codes of the
+    rows of ``x`` (C, d), where d = M·ds; only the first ``n`` rows (all by
+    default) are assigned, later ones keep code 0. Wider rows are read on
+    their first d columns (the stored corpus, ``estimator.stored_width``).
 
-    Rows are assigned in blocks of ``ASSIGN_ROWS`` (one block when N is
-    smaller), so device memory stays bounded at any corpus size."""
-    n, m, ds = xs.shape
+    Rows are assigned in blocks of ``ASSIGN_ROWS`` (one block when n is
+    smaller), so device memory stays bounded at any corpus size. A
+    whole-corpus (C, M, ds) view is never made: the TPU pads its ds-wide
+    minor dimension to 128 lanes (16 GiB at C=1M, d=128)."""
+    m, _, ds = centroids.shape
+    n = x.shape[0] if n is None else n
     rows = _block_rows(n)
-    codes = jax.lax.map(
-        lambda b: _assign_block(centroids, b.reshape(rows, m, ds)),
-        _subspace_blocks(xs, rows))
-    return codes.reshape(-1, m)[:n]
+
+    def one(i, codes):
+        start, xb, own = _row_block(x, i, rows, m * ds, n)
+        new = _assign_block(centroids, xb.reshape(rows, m, ds))
+        return _put_rows(codes, start, new, own)
+
+    return jax.lax.fori_loop(0, -(-n // rows), one,
+                             jnp.zeros((x.shape[0], m), jnp.int32))
 
 
-def _centroid_sums(xs: jax.Array, codes: jax.Array, kc: int):
+def _centroid_sums(x: jax.Array, codes: jax.Array, kc: int, ds: int,
+                   n: int):
     """Per-(subspace, centroid) coordinate sums (M, Kc, ds) and member
-    counts (M, Kc) of the points ``xs`` (N, M, ds) under ``codes`` (N, M),
-    accumulated over the row blocks of :func:`assign` (a whole (N·M, ds)
-    scatter source is lane-padded like the view :func:`_subspace_blocks`
-    avoids)."""
-    n, m, ds = xs.shape
+    counts (M, Kc) of the first ``n`` rows of ``x`` (C, >= M·ds) under
+    ``codes`` (C, M), accumulated block by block as :func:`assign` reads
+    them."""
+    m = codes.shape[1]
     rows = _block_rows(n)
     offs = (jnp.arange(m, dtype=jnp.int32) * kc)[None, :]
 
-    def one(acc, blk):
-        xb, cb = blk
-        seg = (cb + offs).reshape(-1)       # padding rows: >= m*kc, dropped
+    def one(i, acc):
+        start, xb, own = _row_block(x, i, rows, m * ds, n)
+        cb = jax.lax.dynamic_slice(codes, (start, 0), (rows, m))
+        # rows not the block's own: segment m*kc, dropped
+        seg = jnp.where(own[:, None], cb + offs, m * kc).reshape(-1)
         sums = jax.ops.segment_sum(xb.reshape(rows * m, ds), seg,
                                    num_segments=m * kc)
         cnts = jax.ops.segment_sum(jnp.ones(seg.shape, jnp.float32), seg,
                                    num_segments=m * kc)
-        return (acc[0] + sums, acc[1] + cnts), None
+        return acc[0] + sums, acc[1] + cnts
 
-    init = (jnp.zeros((m * kc, ds), xs.dtype), jnp.zeros((m * kc,),
-                                                         jnp.float32))
-    (sums, cnts), _ = jax.lax.scan(
-        one, init, (_subspace_blocks(xs, rows),
-                    _row_blocks(codes, rows, fill=m * kc)))
+    init = (jnp.zeros((m * kc, ds), x.dtype), jnp.zeros((m * kc,),
+                                                        jnp.float32))
+    sums, cnts = jax.lax.fori_loop(0, -(-n // rows), one, init)
     return sums.reshape(m, kc, ds), cnts.reshape(m, kc)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def fit(x: jax.Array, cfg: ProberConfig, key: jax.Array) -> PQIndex:
-    """Lloyd's k-means per subspace, vectorised across all M subspaces.
+@partial(jax.jit, static_argnames=("cfg", "n_valid", "dim"))
+@jax.named_scope("build/pq_fit")
+def kmeans(x: jax.Array, cfg: ProberConfig, key: jax.Array,
+           n_valid: int | None = None, dim: int | None = None) -> jax.Array:
+    """Lloyd's k-means per subspace, vectorised across all M subspaces:
+    the (M, Kc, ds) codebook of the first ``n_valid`` rows of ``x``
+    (C, >= dim) on their first ``dim`` columns (all of them by default).
 
-    Jitted as one program: run op by op, the (N, M, ds) subspace view
-    would be materialised with its ds-wide minor dimension padded to 128
-    lanes on a TPU — 16 GiB at N=1M, d=128."""
+    Every pass reads ``x`` in row blocks (:func:`assign`,
+    :func:`_centroid_sums`), so the temporaries are a few blocks, never a
+    copy of the corpus. Give it the capacity-padded corpus
+    (``estimator.build``): its width is a multiple of 128, so the TPU keeps
+    it row-major and reads the blocks in place, where a (1M, 960) corpus
+    is laid out by columns and would be copied whole."""
     m, kc = cfg.pq_m, cfg.pq_kc
     assert kc <= 256, f"Kc={kc} must fit a uint8 code"
-    xs = split_subspaces(x, m)                               # (N, M, ds)
-    n = xs.shape[0]
+    n = x.shape[0] if n_valid is None else n_valid
+    d = x.shape[1] if dim is None else dim
+    assert d % m == 0, f"M={m} must divide d={d}"
     init_rows = jax.random.choice(key, n, (kc,), replace=n < kc)
-    centroids = jnp.swapaxes(xs[init_rows], 0, 1)            # (M, Kc, ds)
+    centroids = jnp.swapaxes(split_subspaces(x[init_rows, :d], m), 0, 1)
 
     def step(centroids, _):
-        codes = assign(centroids, xs)                        # (N, M)
-        sums, cnts = _centroid_sums(xs, codes, kc)
+        codes = assign(centroids, x, n)                      # (C, M)
+        sums, cnts = _centroid_sums(x, codes, kc, d // m, n)
         new = jnp.where(cnts[..., None] > 0, sums / jnp.maximum(cnts[..., None], 1.0),
                         centroids)
         return new, None
 
     centroids, _ = jax.lax.scan(step, centroids, None, length=cfg.pq_iters)
-    codes = assign(centroids, xs)
-    _, counts = _centroid_sums(xs, codes, kc)
-    resid = reconstruction_residual(centroids, codes, xs)
+    return centroids
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_valid"))
+@jax.named_scope("build/pq_encode")
+def encode(x: jax.Array, centroids: jax.Array, cfg: ProberConfig,
+           n_valid: int | None = None) -> PQIndex:
+    """The index of the first ``n_valid`` rows of ``x`` (C, >= M·ds) under
+    a codebook: codes, member counts and residuals, in row blocks. Rows
+    from ``n_valid`` on are capacity padding (DESIGN.md §10): their codes
+    and residuals are 0, as :func:`grow` pads them."""
+    m, kc, ds = centroids.shape
+    n = x.shape[0] if n_valid is None else n_valid
+    codes = assign(centroids, x, n)
+    _, counts = _centroid_sums(x, codes, kc, ds, n)
+    resid = reconstruction_residual(centroids, codes, x, n)
     codes8 = codes.astype(jnp.uint8)
     packed = None
     if cfg.pq_pack4:
@@ -166,6 +203,12 @@ def fit(x: jax.Array, cfg: ProberConfig, key: jax.Array) -> PQIndex:
     return PQIndex(centroids=centroids, codes=codes8,
                    counts=counts, resid=resid,
                    n_valid=jnp.asarray(n, jnp.int32), packed=packed)
+
+
+def fit(x: jax.Array, cfg: ProberConfig, key: jax.Array) -> PQIndex:
+    """PQ index of every row of ``x`` (N, d): :func:`kmeans`, then
+    :func:`encode`."""
+    return encode(x, kmeans(x, cfg, key), cfg)
 
 
 def grow(pq: PQIndex, new_capacity: int) -> PQIndex:
@@ -202,26 +245,32 @@ def unpack_codes(packed: jax.Array) -> jax.Array:
 
 
 def reconstruction_residual(centroids: jax.Array, codes: jax.Array,
-                            xs: jax.Array) -> jax.Array:
-    """||x - q(x)|| per point; xs is (N, M, ds). Row-blocked like
-    :func:`assign`."""
-    n, m, ds = xs.shape
+                            x: jax.Array, n: int | None = None) -> jax.Array:
+    """||x - q(x)|| per row of ``x`` (C, >= M·ds) under ``codes`` (C, M),
+    for the first ``n`` rows (all by default; later ones read 0).
+    Row-blocked like :func:`assign`."""
+    m, _, ds = centroids.shape
+    n = x.shape[0] if n is None else n
     rows = _block_rows(n)
 
-    def one(blk):
-        cb, xb = blk
+    def one(i, resid):
+        start, xb, own = _row_block(x, i, rows, m * ds, n)
+        cb = jax.lax.dynamic_slice(codes, (start, 0), (rows, m))
         recon = centroids[jnp.arange(m)[None, :], cb]     # (rows, M, ds)
         diff = xb.reshape(rows, m, ds) - recon
-        return jnp.sqrt(jnp.sum(diff ** 2, axis=(-1, -2)))
+        return _put_rows(resid, start,
+                         jnp.sqrt(jnp.sum(diff ** 2, axis=(-1, -2))), own)
 
-    resid = jax.lax.map(one, (_row_blocks(codes, rows),
-                              _subspace_blocks(xs, rows)))
-    return resid.reshape(-1)[:n]
+    return jax.lax.fori_loop(0, -(-n // rows), one,
+                             jnp.zeros((x.shape[0],), centroids.dtype))
 
 
 def adc_table(pq: PQIndex, q: jax.Array) -> jax.Array:
-    """Alg. 4: per-query LUT ``T[m, c] = ||q_m - centroid[m,c]||^2`` (M, Kc)."""
-    qs = q.reshape(pq.m, -1)                                 # (M, ds)
+    """Alg. 4: per-query LUT ``T[m, c] = ||q_m - centroid[m,c]||^2`` (M, Kc).
+    A query as wide as the stored corpus (``estimator.stored_width``) is
+    read on its first d columns, as :func:`assign` reads the corpus."""
+    m, _, ds = pq.centroids.shape
+    qs = q[:m * ds].reshape(m, ds)                           # (M, ds)
     diff = qs[:, None, :] - pq.centroids                     # (M, Kc, ds)
     return jnp.sum(diff ** 2, axis=-1)
 

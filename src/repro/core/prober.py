@@ -397,7 +397,11 @@ def _run_one_table(view: TableView, qcode: jax.Array, qualfn: QualFn,
 
 def make_exact_qualfn(x: jax.Array, q: jax.Array, tau_sq: jax.Array,
                       use_kernels: bool = False) -> QualFn:
-    """Exact squared-L2 qualification (Def. 3): 1[d^2 <= tau^2]."""
+    """Exact squared-L2 qualification (Def. 3): 1[d^2 <= tau^2]. The
+    corpus may be stored wider than ``q`` (``estimator.stored_width``):
+    ``q`` gets the same zero columns."""
+    q = jnp.pad(q, (0, x.shape[-1] - q.shape[-1]))
+
     def fn(ids: jax.Array) -> jax.Array:
         rows = x[ids]                       # (c, d)
         if use_kernels:

@@ -155,7 +155,8 @@ def _pq_ingest(pq: pqmod.PQIndex, x_all: jax.Array, x_new: jax.Array,
     """Fixed-shape Alg. 8 step over the capacity-padded code/resid arrays.
 
     ``x_all`` is the capacity-padded corpus WITH the new rows already
-    written at ``[n_valid, n_valid + n_new)`` — needed because the moved
+    written at ``[n_valid, n_valid + n_new)``, at its stored width (read
+    on its first d columns) — needed because the moved
     centroids invalidate every affected point's stored residual, so all
     live residuals are recomputed against the post-update centroids.
     """
@@ -165,7 +166,7 @@ def _pq_ingest(pq: pqmod.PQIndex, x_all: jax.Array, x_new: jax.Array,
     xs_new = pqmod.split_subspaces(x_new, m)              # (Nn, M, ds)
     ds = xs_new.shape[-1]
     # paper's rule: new points take the nearest of the OLD centroids
-    new_codes = pqmod.assign(pq.centroids, xs_new)        # (Nn, M)
+    new_codes = pqmod.assign(pq.centroids, x_new)         # (Nn, M)
     wvalid = (jnp.arange(nn_pad) < n_new)
     seg = (new_codes + (jnp.arange(m, dtype=jnp.int32) * kc)[None, :]).reshape(-1)
     wf = jnp.repeat(wvalid.astype(jnp.float32), m)
@@ -190,9 +191,8 @@ def _pq_ingest(pq: pqmod.PQIndex, x_all: jax.Array, x_new: jax.Array,
     nv2 = pq.n_valid + n_new
     # refresh EVERY live residual against the moved centroids — old points
     # would otherwise keep residuals of the pre-update codebook
-    xs_all = pqmod.split_subspaces(x_all, m)
     resid = pqmod.reconstruction_residual(new_centroids,
-                                          codes.astype(jnp.int32), xs_all)
+                                          codes.astype(jnp.int32), x_all)
     resid = jnp.where(jnp.arange(cap) < nv2, resid, 0.0)
     return pqmod.PQIndex(centroids=new_centroids, codes=codes, counts=tot,
                          resid=resid, n_valid=nv2, packed=packed)

@@ -8,7 +8,10 @@ HBM fails here. The topology is described inside a fixture, never while
 this module is imported, so test collection is the same in every worker
 and only the worker that runs this file loads the TPU compiler.
 """
+import json
 import os
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,12 +20,15 @@ from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
 from benchmarks.common import serve_cfg
-from repro.core import estimator as E
+from repro.core import estimator as E, pq
+from repro.core.config import ProberConfig
 from repro.kernels import adc, hamming, l2dist, lsh_hash
 
 N = 65536          # rows per kernel call
 Q = 64             # queries of the batched ADC kernels
 CAPACITY = 2 ** 20
+GIST = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / \
+    "chip" / "configs" / "gist1m.json"
 
 
 @pytest.fixture(scope="module")
@@ -142,3 +148,50 @@ def test_cached_flush_steps_compile_for_one_chip(one_chip, check_ingest):
         match_qhash=True).compile()
     for compiled in (lookup, insert):
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def _gist_cfg() -> ProberConfig:
+    """The prober settings the gist1m benchmark cell serves."""
+    return ProberConfig(**json.loads(GIST.read_text())["prober"])
+
+
+def test_gist_pq_build_fits_one_chip(one_chip):
+    """The PQ build of a 1,000,000 x 960 corpus (M = 32), as
+    ``E.build(capacity=2^20)`` runs it on the stored corpus, reads row
+    blocks in place: k-means and encoding each hold at most 2 GiB of
+    temporaries, where a padded copy of the corpus made the build ask
+    14.9 GB."""
+    x = jax.ShapeDtypeStruct((CAPACITY, E.stored_width(960)), jnp.float32,
+                             sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    cfg = _gist_cfg()
+    cents = jax.ShapeDtypeStruct((32, cfg.pq_kc, 30), jnp.float32,
+                                 sharding=one_chip)
+    for compiled in (
+            pq.kmeans.lower(x, cfg, key, n_valid=1_000_000,
+                            dim=960).compile(),
+            pq.encode.lower(x, cents, cfg, n_valid=1_000_000).compile()):
+        assert compiled.memory_analysis().temp_size_in_bytes <= 2 * 2 ** 30
+
+
+def test_gist_flush_reads_the_corpus_in_place(one_chip):
+    """The gist1m cell's flush (estimate_batch_stats, exact central
+    bucket, capacity 2^20, 8 queries) on the corpus stored 1,024 wide:
+    the probe's row gathers read it as laid out, so the program holds no
+    copy of a corpus-sized operand and at most 2 GiB of temporaries."""
+    cfg = _gist_cfg()
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    state = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda x, k: E.attach_epochs(E.build(x, cfg, k, capacity=CAPACITY)),
+        jax.ShapeDtypeStruct((1_000_000, 960), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    assert state.x.shape == (CAPACITY, 1024)
+    q = chip_smoke.FLUSH
+    compiled = E.estimate_batch_stats.lower(
+        state, place(jax.ShapeDtypeStruct((q, 960), jnp.float32)),
+        place(jax.ShapeDtypeStruct((q,), jnp.float32)), cfg,
+        place(jax.ShapeDtypeStruct((2,), jnp.uint32))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * 2 ** 30
+    corpus_copy = re.compile(rf"= f32\[{CAPACITY},\d+\]\S* copy\(")
+    assert not corpus_copy.search(compiled.as_text())

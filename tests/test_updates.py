@@ -247,3 +247,58 @@ def test_engine_run_returns_midrun_and_preadmitted_requests():
     assert all(r.done for r in done)
     # nothing is returned twice
     assert eng.run(max_steps=4) == []
+
+
+def test_exact_mode_is_exact_at_a_width_off_the_lanes():
+    """At d = 120 the capacity-padded corpus is stored 128 columns wide.
+    With every ring walked whole (no PQ, budgets above the corpus), the
+    probe returns the brute-force count exactly, before and after one
+    ingest through the coalescer, for queries sent at their own width."""
+    from repro.data import vectors
+    from repro.serve.engine import CardinalityCoalescer
+    d, n0, n_new = 120, 1536, 256
+    x = np.asarray(vectors.make_corpus(jax.random.PRNGKey(4), n0 + n_new, d))
+    cfg = ProberConfig(n_tables=1, n_funcs=8, ring_budget=4096,
+                       central_budget=4096, chunk=256, eps=0.0, s1=1.0,
+                       max_visit=1 << 20, ingest_chunk=n_new)
+    st = E.build(jnp.asarray(x[:n0]), cfg, jax.random.PRNGKey(0),
+                 capacity=4096)
+    assert st.x.shape == (4096, E.stored_width(d)) == (4096, 128)
+    coal = CardinalityCoalescer(st, cfg, jax.random.PRNGKey(1), max_batch=8,
+                                cache_size=64)
+    qs = x[:8] + 0.01
+    d2 = ((x[None, :, :] - qs[:, None, :]) ** 2).sum(-1)
+    taus = np.sqrt(np.sort(d2[:, :n0], axis=1)[:, [0, 5, 40, 300, 900, 1,
+                                                   20, 700]].diagonal())
+    for n_live in (n0, n0 + n_new):
+        if n_live > n0:
+            coal.ingest(x[n0:])
+        reqs = [coal.submit(q, t * 1.0001) for q, t in zip(qs, taus)]
+        res = coal.flush()
+        want = (d2[:, :n_live] <= (taus[:, None] * 1.0001) ** 2).sum(1)
+        np.testing.assert_array_equal([res[r.rid] for r in reqs], want)
+        assert all(res[r.rid].provenance != "hit" for r in reqs)
+    assert int(coal.state.n_valid) == n0 + n_new
+
+
+@pytest.mark.parametrize("int8_lut", [False, True])
+def test_queries_at_the_stored_width_give_the_same_estimates(int8_lut):
+    """A query may come as wide as the stored corpus, zero past d (the
+    probe step is lowered so for its compiled text): at d = 120 with PQ
+    rings, the estimates, rings and visits equal those of the same
+    queries at d."""
+    from repro.data import vectors
+    d = 120
+    x = vectors.make_corpus(jax.random.PRNGKey(5), 3000, d)
+    cfg = ProberConfig(n_tables=2, n_funcs=8, chunk=128, use_pq=True,
+                       pq_m=8, pq_kc=16, pq_iters=4, pq_int8_lut=int8_lut,
+                       central_budget=64)
+    st = E.build(x, cfg, jax.random.PRNGKey(0), capacity=4096)
+    qs = x[:8] + 0.01
+    taus = jnp.linspace(0.5, 3.0, 8)
+    wide = jnp.pad(qs, ((0, 0), (0, st.x.shape[1] - d)))
+    key = jax.random.PRNGKey(2)
+    at_d = E.estimate_batch_stats(st, qs, taus, cfg, key)
+    at_stored = E.estimate_batch_stats(st, wide, taus, cfg, key)
+    for a, b in zip(at_d, at_stored):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
